@@ -1,173 +1,31 @@
-"""End-to-end driver: multi-model serving with REAL model execution.
+"""CPU rehearsal of the multi-model server, at smoke size.
 
-Three reduced architectures (dense GQA, MoE, SSM) are served concurrently on
-CPU: the paper's scheduler assigns them to gpu-lets whose L(b, p) profiles
-are *measured* from the actual jitted forward passes (p scales modeled as
-partition-throughput), then batched Poisson traffic is replayed through the
-real models, executing every batch with jax and checking outputs/SLOs.
+Three reduced architectures (dense GQA, MoE, SSM) go through the same path
+as ``python -m repro.launch.serve`` on the chip: parameters initialised
+under jit, prefill and decode compiled per batch bucket, L(b) measured,
+Elastic Partitioning over the local device, and a seeded Poisson trace
+answered with greedy prefill plus decode.  Timings here are CPU wall time.
 
-Run:  PYTHONPATH=src python examples/serve_multimodel.py [--horizon 8]
+Run:  PYTHONPATH=src python examples/serve_multimodel.py [--requests 12]
 """
 import argparse
-import dataclasses
-import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.core import ElasticPartitioning
-from repro.core.hardware import AcceleratorSpec, ClusterSpec
-from repro.core.profiles import ModelProfile
-from repro.models import Model
-from repro.simulator.events import PoissonArrivals, merge_sorted
+from repro.launch import serve
 
 ARCHS = ("yi-9b", "deepseek-moe-16b", "mamba2-780m")
 
 
-def measure_profile(name, model, params, slo_ms, batches=(1, 4, 8, 16, 32)):
-    """Measured L(b) on CPU -> a calibrated ModelProfile for the scheduler."""
-    lat = {}
-    fwd = jax.jit(lambda p, t: model.forward(p, t)[0])
-    for b in batches:
-        toks = {"tokens": jnp.zeros((b, 32), jnp.int32)}
-        fwd(params, toks)  # compile
-        t0 = time.perf_counter()
-        for _ in range(3):
-            jax.block_until_ready(fwd(params, toks))
-        lat[b] = (time.perf_counter() - t0) / 3 * 1e3
-    # fit the analytic profile shape: t0 + c*b (CPU is ~linear in batch)
-    c = (lat[32] - lat[1]) / 31.0
-    prof = ModelProfile(
-        name=name, slo_ms=slo_ms, flops_per_req=0.0, weight_mb=0.0,
-        act_mb_per_req=0.0, par1=0.15, par_exp=0.5, t0_ms=max(lat[1] - c, 0.1),
-        l2_util_base=0.5, efficiency=1.0)
-    return prof, lat
-
-
-class MeasuredLatency:
-    """LatencyProvider over measured CPU latencies (partition = share)."""
-
-    from repro.core.latency import (BATCH_SIZES as batch_sizes,
-                                    MAX_BATCH as max_batch,
-                                    PARTITION_SIZES as partition_sizes,
-                                    SPLIT_PAIRS as split_pairs)
-
-    def __init__(self, tables):
-        self.tables = tables  # name -> {b: ms at full partition}
-
-    def latency_ms(self, prof, batch, p):
-        t = self.tables[prof.name]
-        bs = sorted(t)
-        b_lo = max([b for b in bs if b <= batch], default=bs[0])
-        b_hi = min([b for b in bs if b >= batch], default=bs[-1])
-        if b_lo == b_hi:
-            base = t[b_lo]
-        else:
-            w = (batch - b_lo) / (b_hi - b_lo)
-            base = (1 - w) * t[b_lo] + w * t[b_hi]
-        return base / max(p, 0.2)  # share of the machine
-
-    def __getattr__(self, item):
-        from repro.core.latency import LatencyProvider
-        return LatencyProvider.__dict__[item].__get__(self)
-
-
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--horizon", type=float, default=8.0, help="seconds")
+    ap.add_argument("--requests", type=int, default=12)
+    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-
-    models, profiles, tables = {}, {}, {}
-    key = jax.random.key(0)
-    for arch in ARCHS:
-        cfg = get_smoke_config(arch)
-        m = Model(cfg)
-        params = m.init(key)
-        models[arch] = (m, params, cfg)
-        prof, lat = measure_profile(arch, m, params, slo_ms=0.0)
-        # paper convention: SLO = 2x batch-32 latency
-        prof = dataclasses.replace(prof, slo_ms=2.0 * lat[32])
-        profiles[arch] = prof
-        tables[arch] = lat
-        print(f"{arch}: L(1)={lat[1]:.1f}ms L(32)={lat[32]:.1f}ms "
-              f"SLO={prof.slo_ms:.0f}ms")
-
-    lat_provider = MeasuredLatency(tables)
-    # ONE device: this CPU executes everything serially, so the scheduler
-    # gets a single partitionable "GPU" and we drive at 30% of its claimed
-    # max (two gpu-lets of one CPU still time-share a single core).
-    cpu = AcceleratorSpec(name="cpu", peak_tflops=0.1, hbm_gbs=50, hbm_gb=64)
-    sched = ElasticPartitioning(profiles, lat=lat_provider,
-                                cluster=ClusterSpec(cpu, n_devices=1))
-    unit = {a: 1.0 for a in ARCHS}
-    lam = sched.max_scale(unit, hi=4096)
-    rates = {a: lam * 0.3 for a in ARCHS}
-    res = sched.schedule(rates)
-    print(f"\nschedule (rates {lam * 0.6:.0f}/s per model): "
-          f"schedulable={res.schedulable}")
-    for gpu in res.gpus:
-        for let in gpu.lets:
-            if let.assignments:
-                print(f"  gpu{gpu.gpu_id} {let.size}%: " + ", ".join(
-                    f"{a.model}(b{a.batch},duty{a.duty_ms:.0f}ms)"
-                    for a in let.assignments))
-
-    # replay real traffic through the real models
-    gen = PoissonArrivals(seed=1)
-    horizon_ms = args.horizon * 1e3
-    reqs = merge_sorted([gen.constant(a, rates[a], profiles[a].slo_ms,
-                                      horizon_ms) for a in ARCHS])
-    print(f"\nreplaying {len(reqs)} requests ({args.horizon:.0f}s)...")
-    # single-queue executor honoring the scheduled batch sizes; batches are
-    # quantized to pre-compiled powers of two (jit shape cache)
-    POW2 = (1, 2, 4, 8, 16, 32)
-    batch_size = {a.model: a.batch for let in res.gpulets
-                  for a in let.assignments}
-    fwds = {a: jax.jit(lambda p, t, m=models[a][0]: m.forward(p, t)[0])
-            for a in ARCHS}
-    for a in ARCHS:
-        for b in POW2:
-            jax.block_until_ready(
-                fwds[a](models[a][1], {"tokens": jnp.zeros((b, 32), jnp.int32)}))
-    queues = {a: [] for a in ARCHS}
-    done = violations = 0
-    t_start = time.perf_counter()
-    idx = 0
-    sim_now = 0.0
-    while idx < len(reqs) or any(queues.values()):
-        now_ms = (time.perf_counter() - t_start) * 1e3
-        while idx < len(reqs) and reqs[idx].arrival_ms <= now_ms:
-            queues[reqs[idx].model].append(reqs[idx])
-            idx += 1
-        ran = False
-        for a in ARCHS:
-            q = queues[a]
-            if not q:
-                continue
-            cap = max(batch_size.get(a, 8), 1)
-            want = min(len(q), cap)
-            b = max(x for x in POW2 if x <= max(want, 1))
-            batch, queues[a] = q[:b], q[b:]
-            toks = {"tokens": jnp.zeros((b, 32), jnp.int32)}
-            out = fwds[a](models[a][1], toks)
-            jax.block_until_ready(out)
-            assert np.all(np.isfinite(np.asarray(out[:, -1, :8], np.float32)))
-            t_done = (time.perf_counter() - t_start) * 1e3
-            for r in batch:
-                done += 1
-                if t_done - r.arrival_ms > r.slo_ms:
-                    violations += 1
-            ran = True
-        if not ran:
-            time.sleep(0.002)
-        if idx >= len(reqs) and not any(queues.values()):
-            break
-    rate = violations / max(done, 1)
-    print(f"completed {done}/{len(reqs)} requests, "
-          f"SLO violations {rate:.2%}")
-    assert done == len(reqs), "requests lost"
+    result = serve.run([get_smoke_config(a) for a in ARCHS],
+                       shapes=serve.SMOKE_SHAPES, n_requests=args.requests,
+                       seed=args.seed)
+    serve.print_run(result)
+    assert all(r.output is not None for r in result.requests), "requests lost"
 
 
 if __name__ == "__main__":

@@ -1,11 +1,17 @@
 """Pallas TPU flash-decode: one query token vs. a long KV cache (GQA).
 
-Decode attention is HBM-bandwidth-bound: the entire KV cache streams through
-VMEM once per step.  The grid is (batch, kv_head, kv_blocks) with kv_blocks
-sequential; each program attends the whole GQA *group* of query heads
-(G = H / Hkv) against one kv-head's cache block, so the cache is read exactly
-once regardless of the query-head count.  Valid-length masking supports both
-dense caches and ring-buffer sliding windows.
+Decode attention is HBM-bandwidth-bound: the valid part of the KV cache
+streams through VMEM once per step.  The grid is (batch, kv_head, kv_blocks)
+with kv_blocks sequential; each program attends the whole GQA *group* of
+query heads (G = H / Hkv) against one kv-head's cache block, so the cache is
+read exactly once regardless of the query-head count.  Valid-length masking
+supports both dense caches and ring-buffer sliding windows.
+
+TPU layout: the per-row lengths are scalar-prefetched into SMEM (they drive
+the index maps, which stop fetching blocks past a row's length).  The cache
+(B, S, Hkv, Dh) is viewed as (B, S, Hkv*Dh) so that one kv head's block is
+(block_s, Dh) on the last two dimensions: sublane-aligned for block_s and
+lane-aligned when Dh is a multiple of 128 (or Hkv == 1).
 """
 from __future__ import annotations
 
@@ -15,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -32,14 +36,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     base = j * block_s
 
     @pl.when(base < length)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)              # (G, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bs, dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                 # (bs, dh)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (G, bs)
@@ -80,29 +84,38 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
     n_s = s // block_s
     scale = 1.0 / (dh ** 0.5)
     qg = q.reshape(b, hkv, g, dh)
+    kf = k_cache.reshape(b, s, hkv * dh)
+    vf = v_cache.reshape(b, s, hkv * dh)
+
+    def kv_map(b_, g_, j, lens):
+        # blocks past the row's length repeat the last live block index, so
+        # the pipeline issues no DMA for them (their compute is skipped too)
+        last = jnp.maximum(lens[b_] - 1, 0) // block_s
+        return (b_, jnp.minimum(j, last), g_)
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_s=block_s,
                                n_s=n_s, window=window)
     out = pl.pallas_call(
         kernel,
-        grid=(b, hkv, n_s),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, g_, j: (b_,)),           # lengths
-            pl.BlockSpec((1, 1, g, dh), lambda b_, g_, j: (b_, g_, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda b_, g_, j: (b_, j, g_, 0)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda b_, g_, j: (b_, j, g_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), lambda b_, g_, j: (b_, g_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, n_s),
+            in_specs=[
+                pl.BlockSpec((1, 1, g, dh),
+                             lambda b_, g_, j, lens: (b_, g_, 0, 0)),
+                pl.BlockSpec((1, block_s, dh), kv_map),
+                pl.BlockSpec((1, block_s, dh), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, dh),
+                                   lambda b_, g_, j, lens: (b_, g_, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g,), jnp.float32),
+                pltpu.VMEM((g,), jnp.float32),
+                pltpu.VMEM((g, dh), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, kf, vf)
     return out.reshape(b, h, dh)

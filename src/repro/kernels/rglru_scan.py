@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, y_ref, h_scr, *, block_t: int):
     ti = pl.program_id(1)
@@ -53,7 +51,7 @@ def rglru_scan(a, b, *, block_t: int = 256, interpret: bool = False):
         out_specs=pl.BlockSpec((1, block_t, w), lambda b_, t: (b_, t, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((w,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
                 chunk: int, n_chunks: int):
@@ -92,7 +90,7 @@ def ssd_scan(xh, dt, a, bmat, cmat, *, chunk: int = 256,
                                lambda b_, h_, c: (b_, c, h_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dt, a, bmat, cmat)

@@ -29,7 +29,7 @@ import time
 import traceback
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core.hardware import TPU_V5E
@@ -181,11 +181,7 @@ def _build_step(cfg, shape_name: str, mesh, *, fsdp_override=None):
 
 
 def _cost_record(compiled) -> dict:
-    # jax's Compiled.cost_analysis() returned a one-element list of dicts
-    # through 0.4.x and a plain dict from 0.5; accept both.
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     txt = compiled.as_text()
     coll = collective_stats(txt)
     raw_bytes = float(cost.get("bytes accessed", 0.0))
@@ -324,16 +320,17 @@ def lower_combo(arch_id: str, shape_name: str, *, multi_pod: bool,
     if model_axis is None:
         mesh = make_production_mesh(multi_pod=multi_pod)
     else:
-        import jax as _jax
         n = 512 if multi_pod else 256
         if multi_pod:
-            mesh = _jax.make_mesh((2, 256 // model_axis, model_axis),
-                                  ("pod", "data", "model"),
-                                  devices=_jax.devices()[:n])
+            mesh = jax.make_mesh((2, 256 // model_axis, model_axis),
+                                 ("pod", "data", "model"),
+                                 devices=jax.devices()[:n],
+                                 axis_types=(AxisType.Auto,) * 3)
         else:
-            mesh = _jax.make_mesh((n // model_axis, model_axis),
-                                  ("data", "model"),
-                                  devices=_jax.devices()[:n])
+            mesh = jax.make_mesh((n // model_axis, model_axis),
+                                 ("data", "model"),
+                                 devices=jax.devices()[:n],
+                                 axis_types=(AxisType.Auto,) * 2)
 
     # 1) production compile (scan-over-layers): THE lowering proof + memory.
     fn, args, kind, fsdp = _build_step(cfg, shape_name, mesh,

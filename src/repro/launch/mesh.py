@@ -5,10 +5,10 @@ importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-import jax
-
-
 import math
+
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +22,18 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for {shape} mesh, have {len(devices)}; "
             "run under launch/dryrun.py which forces "
             "--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_serving_mesh(devices):
+    """A (1, n) ("data", "model") mesh: tensor parallel over ``devices``.
+
+    One device gives a (1, 1) mesh, on which every sharding rule of
+    launch/sharding.py resolves to replication on that device.
+    """
+    return jax.make_mesh((1, len(devices)), ("data", "model"),
+                         devices=devices, axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
@@ -41,4 +52,5 @@ def make_submesh(n_chips: int, *, model_axis: int = 16):
     assert n_chips % model_axis == 0, (n_chips, model_axis)
     devices = jax.devices()[:n_chips]
     return jax.make_mesh((n_chips // model_axis, model_axis),
-                         ("data", "model"), devices=devices)
+                         ("data", "model"), devices=devices,
+                         axis_types=(AxisType.Auto,) * 2)

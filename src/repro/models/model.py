@@ -33,6 +33,13 @@ class Model:
     # ------------------------------------------------------------- init ----
 
     def init(self, key) -> dict:
+        """Random parameters from ``key``.
+
+        Run it under ``jax.jit`` for full-size models: XLA then fuses each
+        weight's draw, scale and cast, and ``lax.map`` makes the layers of a
+        homogeneous stack one at a time, so the float32 transient is at most
+        one layer's weight, never a whole stacked weight.
+        """
         cfg = self.cfg
         k_embed, k_layers, k_norm = jax.random.split(key, 3)
         ninit, _ = make_norm(cfg.norm)
@@ -48,8 +55,8 @@ class Model:
         kinds = cfg.layer_types()
         keys = jax.random.split(k_layers, cfg.n_layers)
         if tfm.is_homogeneous(cfg):
-            params["layers"] = jax.vmap(
-                lambda k: tfm.init_layer(k, kinds[0], cfg, self.dtype))(keys)
+            params["layers"] = jax.lax.map(
+                lambda k: tfm.init_layer(k, kinds[0], cfg, self.dtype), keys)
         else:
             params["layers"] = [
                 tfm.init_layer(keys[i], kinds[i], cfg, self.dtype)

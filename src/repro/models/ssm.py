@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 
 CONV_K = 4  # depthwise conv kernel width
+DT_MIN, DT_MAX = 1e-3, 1e-1  # published Mamba-2 step-size init range
 
 
 def ssm_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
@@ -33,6 +34,11 @@ def ssm_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     nh = cfg.ssm_n_heads
     keys = jax.random.split(key, 7)
     s = 1.0 / math.sqrt(d)
+    # step sizes log-uniform in [DT_MIN, DT_MAX] through an inverse-softplus
+    # bias, as the published init does; a zero bias gives dt ~ 0.7, a stack
+    # far more sensitive to rounding than the published model.
+    dt = jnp.exp(jax.random.uniform(keys[6], (nh,))
+                 * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
     # Separate (not fused) projections so the head dim shards cleanly on the
     # "model" mesh axis (w_z/w_x/conv/w_dt on heads; w_bc replicated).
     return {
@@ -42,7 +48,7 @@ def ssm_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
         "w_dt": (jax.random.normal(keys[3], (d, nh)) * s).astype(dtype),
         "conv": (jax.random.normal(keys[4], (CONV_K, di)) / CONV_K).astype(dtype),
         "a_log": jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(jnp.float32),
-        "dt_bias": jnp.zeros((nh,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "d_skip": jnp.ones((nh,), jnp.float32),
         "w_out": (jax.random.normal(keys[5], (di, d)) /
                   math.sqrt(di)).astype(dtype),

@@ -17,7 +17,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, sys
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.launch import sharding as shr
 from repro.launch.dryrun import collective_stats, _cost_record
@@ -27,7 +27,8 @@ from repro.training.optim import OptimConfig, adamw_init
 from repro.training.train import make_train_step
 
 arch = sys.argv[1]
-mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices(),
+                     axis_types=(AxisType.Auto,) * 2)
 set_mesh_context(mesh, ("data",))
 cfg = get_smoke_config(arch)
 model = Model(cfg)
