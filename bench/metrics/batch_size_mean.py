@@ -1,0 +1,6 @@
+"""Requests per ``generate`` call, over the window's batches."""
+
+
+def read(record, arg):
+    sizes = [b["size"] for b in record["batches"]]
+    return sum(sizes) / len(sizes) if sizes else None

@@ -1,0 +1,7 @@
+"""Decode step of model ``arg`` at its largest batch bucket, from
+``ModelRunner.measure()`` (host clock around ``block_until_ready``)."""
+
+
+def read(record, arg):
+    table = record["models"].get(arg, {}).get("decode_ms")
+    return table[max(table)] if table else None
