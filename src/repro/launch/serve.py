@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import math
 import os
 import time
 from collections.abc import Callable, Sequence
@@ -50,6 +51,8 @@ from repro.launch.mesh import make_serving_mesh
 from repro.models.config import ModelConfig, round_up
 from repro.models.model import Model
 from repro.models.shard_ctx import mesh_context
+from repro.obs.served import RECORDER, Window
+from repro.obs.spans import BatchSpan, HostSpan, ServedBatch, SleepSpan
 from repro.simulator.events import PoissonArrivals, merge_sorted
 
 #: One 16x16 v5e pod treated as a single partitionable "device".
@@ -124,6 +127,11 @@ class Generation(NamedTuple):
     tokens: np.ndarray          # (B, n_new) greedy tokens
     logits: list                # n_new device arrays (B, V) float32
     finite: bool                # every logit computed was finite
+    # host stamps (time.perf_counter): prefill dispatched, first token on
+    # the host, last token on the host
+    dispatch_s: float = math.nan
+    first_token_s: float = math.nan
+    done_s: float = math.nan
 
 
 def _greedy(logits):
@@ -181,7 +189,7 @@ class ModelRunner:
 
     def compile(self, batches: Sequence[int] | None = None,
                 prompt_lens: Sequence[int] | None = None) -> None:
-        """Compile and run once every (batch, prompt length) program."""
+        """Compile every (batch, prompt length) program, then run each once."""
         model, max_len = self.model, self.shapes.max_len
         batches = batches or self.shapes.batch_buckets
         prompt_lens = prompt_lens or self.shapes.prompt_lens
@@ -195,38 +203,58 @@ class ModelRunner:
             logits, cache = model.decode_step(params, cache, tok)
             return (*_greedy(logits), cache)
 
-        for b in batches:
-            c_sh = shr.cache_shardings(self.cfg, model.cache_shapes(b, max_len),
-                                       self.mesh)
-            step_sds, step_sh = self._tokens(b, 1)
-            outs = (step_sh, self.rep, self.rep, c_sh)
-            for s in prompt_lens:
-                sds, t_sh = self._tokens(b, s)
-                self._prefill[(b, s)] = self._compile(
-                    jax.jit(prefill, in_shardings=(self.param_sh, t_sh),
-                            out_shardings=outs), self.params, sds)
-            self._decode[b] = self._compile(
-                jax.jit(decode, in_shardings=(self.param_sh, c_sh, step_sh),
-                        out_shardings=outs, donate_argnums=1),
-                self.params, model.cache_shapes(b, max_len), step_sds)
-            for s in prompt_lens:  # warm-up: every program runs once
-                self.generate(np.zeros((b, s), np.int32), 2)
+        with RECORDER.setup_span("compile", self.name):
+            for b in batches:
+                c_sh = shr.cache_shardings(
+                    self.cfg, model.cache_shapes(b, max_len), self.mesh)
+                step_sds, step_sh = self._tokens(b, 1)
+                outs = (step_sh, self.rep, self.rep, c_sh)
+                for s in prompt_lens:
+                    sds, t_sh = self._tokens(b, s)
+                    self._prefill[(b, s)] = self._compile(
+                        jax.jit(prefill, in_shardings=(self.param_sh, t_sh),
+                                out_shardings=outs), self.params, sds)
+                self._decode[b] = self._compile(
+                    jax.jit(decode, in_shardings=(self.param_sh, c_sh, step_sh),
+                            out_shardings=outs, donate_argnums=1),
+                    self.params, model.cache_shapes(b, max_len), step_sds)
+        with RECORDER.setup_span("warmup", self.name):
+            for b in batches:
+                for s in prompt_lens:  # every program runs once
+                    self.generate(np.zeros((b, s), np.int32), 2)
 
     def generate(self, prompts: np.ndarray, n_new: int) -> Generation:
-        """Greedy prefill plus ``n_new - 1`` decode steps for one batch."""
+        """Greedy prefill plus ``n_new - 1`` decode steps for one batch.
+
+        Every step is queued before the first token comes to the host, so
+        the device never waits on the host inside a batch, however late
+        the host wakes; the first token's stamp is thus no earlier than
+        the last step's dispatch.  ``serve.prefill/<model>`` spans dispatch
+        to first token on the host, ``serve.decode/<model>`` first to last
+        token.
+        """
         b, s = prompts.shape
         _, t_sh = self._tokens(b, s)
-        tok, last, ok, cache = self._prefill[(b, s)](
-            self.params, jax.device_put(prompts, t_sh))
-        toks, logits, oks = [tok], [last], [ok]
-        decode = self._decode[b]
-        for _ in range(n_new - 1):
-            tok, last, ok, cache = decode(self.params, cache, tok)
-            toks.append(tok)
-            logits.append(last)
-            oks.append(ok)
-        tokens = np.concatenate(jax.device_get(toks), axis=1)
-        return Generation(tokens, logits, bool(np.all(jax.device_get(oks))))
+        with jax.profiler.TraceAnnotation(f"serve.prefill/{self.name}"):
+            dispatch_s = time.perf_counter()
+            tok, last, ok, cache = self._prefill[(b, s)](
+                self.params, jax.device_put(prompts, t_sh))
+            tok.copy_to_host_async()
+            toks, logits, oks = [tok], [last], [ok]
+            decode = self._decode[b]
+            for _ in range(n_new - 1):
+                tok, last, ok, cache = decode(self.params, cache, tok)
+                toks.append(tok)
+                logits.append(last)
+                oks.append(ok)
+            np.asarray(toks[0])
+        with jax.profiler.TraceAnnotation(f"serve.decode/{self.name}"):
+            first_token_s = time.perf_counter()
+            tokens = np.concatenate(jax.device_get(toks), axis=1)
+            finite = bool(np.all(jax.device_get(oks)))
+            done_s = time.perf_counter()
+        return Generation(tokens, logits, finite, dispatch_s, first_token_s,
+                          done_s)
 
     def forward_logits(self, seq: np.ndarray, n_last: int) -> np.ndarray:
         """Teacher-forced float32 logits of the last ``n_last`` positions."""
@@ -249,22 +277,24 @@ class ModelRunner:
         """L(b) per batch bucket: median prefill of the longest prompt plus
         (longest generation - 1) median decode steps, all on the device."""
         s, g = max(self.shapes.prompt_lens), self.shapes.gen_range[1]
-        for b in self.shapes.batch_buckets:
-            prompts = jax.device_put(np.zeros((b, s), np.int32),
-                                     self._tokens(b, s)[1])
-            pre, dec = [], []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                tok, _, _, cache = jax.block_until_ready(
-                    self._prefill[(b, s)](self.params, prompts))
-                pre.append(time.perf_counter() - t0)
-                t0 = time.perf_counter()
-                tok, _, _, cache = jax.block_until_ready(
-                    self._decode[b](self.params, cache, tok))
-                dec.append(time.perf_counter() - t0)
-            self.prefill_ms[b] = float(np.median(pre)) * 1e3
-            self.decode_ms[b] = float(np.median(dec)) * 1e3
-            self.latency_ms[b] = self.prefill_ms[b] + (g - 1) * self.decode_ms[b]
+        with RECORDER.setup_span("measure", self.name):
+            for b in self.shapes.batch_buckets:
+                prompts = jax.device_put(np.zeros((b, s), np.int32),
+                                         self._tokens(b, s)[1])
+                pre, dec = [], []
+                for _ in range(reps):
+                    t0 = time.perf_counter()
+                    tok, _, _, cache = jax.block_until_ready(
+                        self._prefill[(b, s)](self.params, prompts))
+                    pre.append(time.perf_counter() - t0)
+                    t0 = time.perf_counter()
+                    tok, _, _, cache = jax.block_until_ready(
+                        self._decode[b](self.params, cache, tok))
+                    dec.append(time.perf_counter() - t0)
+                self.prefill_ms[b] = float(np.median(pre)) * 1e3
+                self.decode_ms[b] = float(np.median(dec)) * 1e3
+                self.latency_ms[b] = (self.prefill_ms[b]
+                                      + (g - 1) * self.decode_ms[b])
 
 
 class MeasuredLatency(LatencyProvider):
@@ -381,38 +411,66 @@ def replay(runners: Sequence[ModelRunner], reqs: list[ServeRequest],
 
     A batch takes the oldest queued request of a model and the queued
     requests with its prompt length, up to the model's scheduled batch,
-    rounded down to a compiled bucket.
+    rounded down to a compiled bucket.  The recorder's window (a fresh one
+    per call) logs each step; a batch enters it once it has answered.
     """
-    queues: dict[str, list[ServeRequest]] = {r.name: [] for r in runners}
-    t_start = time.perf_counter()
+    queues: dict[str, list[int]] = {r.name: [] for r in runners}
+    log = RECORDER.open_window(reqs)
+    t_start = log.t0_s
     idx = 0
-    while idx < len(reqs) or any(queues.values()):
-        now_ms = (time.perf_counter() - t_start) * 1e3
-        while idx < len(reqs) and reqs[idx].arrival_ms <= now_ms:
-            queues[reqs[idx].model].append(reqs[idx])
-            idx += 1
-        ran = False
-        for runner in runners:
-            q = queues[runner.name]
-            if not q:
-                continue
-            s = len(q[0].prompt)
-            group = [r for r in q if len(r.prompt) == s]
-            want = min(len(group), caps[runner.name])
-            b = max(x for x in runner.shapes.batch_buckets if x <= want)
-            batch = group[:b]
-            queues[runner.name] = [r for r in q if r not in batch]
-            out = runner.generate(np.stack([r.prompt for r in batch]),
-                                  max(r.max_new for r in batch))
-            if not out.finite:
-                raise FloatingPointError(f"{runner.name}: non-finite logits")
-            t_done = (time.perf_counter() - t_start) * 1e3
-            for i, r in enumerate(batch):
-                r.output = out.tokens[i, :r.max_new]
-                r.completion_ms = t_done
-            ran = True
-        if not ran and idx < len(reqs):
-            time.sleep(max(reqs[idx].arrival_ms - now_ms, 0.0) / 1e3)
+    try:
+        while idx < len(reqs) or any(queues.values()):
+            now_ms = (time.perf_counter() - t_start) * 1e3
+            while idx < len(reqs) and reqs[idx].arrival_ms <= now_ms:
+                queues[reqs[idx].model].append(idx)
+                log.admitted_ms[idx] = now_ms
+                idx += 1
+            ran = False
+            for runner in runners:
+                q = queues[runner.name]
+                if not q:
+                    continue
+                with jax.profiler.TraceAnnotation("serve.form"):
+                    t_form = time.perf_counter()
+                    s = len(reqs[q[0]].prompt)
+                    group = [i for i in q if len(reqs[i].prompt) == s]
+                    want = min(len(group), caps[runner.name])
+                    b = max(x for x in runner.shapes.batch_buckets if x <= want)
+                    ids = group[:b]
+                    queues[runner.name] = [i for i in q if i not in ids]
+                    batch = [reqs[i] for i in ids]
+                    prompts = np.stack([r.prompt for r in batch])
+                    n_new = max(r.max_new for r in batch)
+                    t_formed = time.perf_counter()
+                log.forms.append(HostSpan("serve.form", log.ms(t_form),
+                                          log.ms(t_formed)))
+                with jax.profiler.TraceAnnotation(f"serve.batch/{runner.name}"):
+                    t_batch = time.perf_counter()
+                    out = runner.generate(prompts, n_new)
+                if not out.finite:
+                    raise FloatingPointError(f"{runner.name}: non-finite logits")
+                t_done = (time.perf_counter() - t_start) * 1e3
+                bid = len(log.batches)
+                log.batches.append(ServedBatch(
+                    BatchSpan("batch", 0, 0, log.ms(t_batch), t_done,
+                              runner.name, b),
+                    bid, s, n_new, tuple(ids), log.ms(out.dispatch_s),
+                    log.ms(out.first_token_s), log.ms(out.done_s)))
+                for k, (i, r) in enumerate(zip(ids, batch)):
+                    r.output = out.tokens[k, :r.max_new]
+                    r.completion_ms = t_done
+                    log.batch[i], log.done_ms[i] = bid, t_done
+                ran = True
+            if not ran and idx < len(reqs):
+                due_ms = reqs[idx].arrival_ms
+                with jax.profiler.TraceAnnotation("serve.sleep"):
+                    t_sleep = time.perf_counter()
+                    time.sleep(max(due_ms - now_ms, 0.0) / 1e3)
+                    woke_ms = (time.perf_counter() - t_start) * 1e3
+                log.sleeps.append(SleepSpan("serve.sleep", log.ms(t_sleep),
+                                            due_ms, woke_ms))
+    finally:
+        log.open = False
 
 
 @dataclasses.dataclass
@@ -421,6 +479,7 @@ class ServeRun:
     rates: dict[str, float]
     placement: ScheduleResult
     requests: list[ServeRequest]
+    window: Window | None = None        # the recorder's log of the replay
 
     def summary(self, runner: ModelRunner) -> dict:
         mine = [r for r in self.requests if r.model == runner.name]
@@ -457,7 +516,7 @@ def run(cfgs: Sequence[ModelConfig], *, shapes: ServeShapes = ServeShapes(),
             caps[a.model] = max(caps[a.model], a.batch)
     reqs = make_trace(runners, rates, mix_slo_ms(runners), n_requests, seed)
     replay(runners, reqs, caps)
-    return ServeRun(runners, rates, placement, reqs)
+    return ServeRun(runners, rates, placement, reqs, RECORDER.window)
 
 
 def agreement(tokens: np.ndarray, logits: np.ndarray,
@@ -558,7 +617,8 @@ def _plan(args) -> int:
 
 
 def print_run(result: ServeRun) -> None:
-    """Placement and per-model results of a served mix, one line each."""
+    """Placement, per-model results of a served mix, one line each, and
+    the window's first-token p90, latest wake and compile count."""
     for gpu in result.placement.gpus:
         for let in gpu.lets:
             if let.assignments:
@@ -569,6 +629,10 @@ def print_run(result: ServeRun) -> None:
         summary = result.summary(r)
         print(f"model {summary.pop('model')}: " + " ".join(
             f"{k}={v}" for k, v in summary.items()))
+    if result.window is not None:
+        w = result.window
+        print(f"window first_token_p90_ms={w.first_token_p90_ms()} "
+              f"wake_late_max_ms={w.wake_late_max_ms()} compiles={w.compiles}")
 
 
 def main(argv: Sequence[str] | None = None) -> int:

@@ -1,8 +1,14 @@
-"""SLO forensics: lifecycle tracing, fleet telemetry, miss attribution.
+"""Observability: the simulator's SLO forensics, and the served path's recorder.
 
-Zero-overhead-when-off observability for the serving stack (ISSUE 8).
-Enable by attaching a :class:`Timeline` to the request trace *before*
-serving::
+The served path on the chip (``repro.launch.serve``: ``replay`` and
+``ModelRunner``) writes :mod:`repro.obs.served`, an always-on in-memory
+recorder of one replay window and of set-up, whose spans are also
+``jax.profiler`` annotations.  It is imported from its module, since it
+needs JAX and the simulator's forensics below do not.
+
+Simulator and fabric: lifecycle tracing, fleet telemetry, miss
+attribution, zero-overhead when off.  Enable by attaching a
+:class:`Timeline` to the request trace *before* serving::
 
     from repro.obs import attach_timeline, collect_attribution, dump_run
 

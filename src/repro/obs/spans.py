@@ -1,4 +1,4 @@
-"""Typed span records for the engine event log (ISSUE 8 satellite).
+"""Typed span records: the engine event log, and the served path's recorder.
 
 The engine's ``self.log`` (gated by ``EngineConfig.event_log``) used to
 hold untyped tuples — ``("batch", epoch, let, launch, done, model, n)``
@@ -11,6 +11,11 @@ Every record's first field is its ``kind`` tag (the ``make_*`` helpers
 fill it); ``SPAN_KINDS`` maps tag → type.  Records are plain tuples
 underneath: they pickle cheaply across forked node workers and
 sort/compare like the tuples they replace.
+
+The served path (``repro.obs.served``) reuses :class:`BatchSpan` for a
+batch that ran on the chip, and adds the records below it for what the
+engine's log has no field for: a served batch's requests and host
+stamps, the replay loop's sleeps, host work, and requests.
 """
 from __future__ import annotations
 
@@ -75,7 +80,58 @@ class TickSpan(NamedTuple):
     resched: bool
 
 
-#: tag -> record type, for validators and exporters
+class ServedBatch(NamedTuple):
+    """One batch ``serve.replay`` ran, in ms since its window's 0.
+
+    ``span`` is the batch as the engine's log holds one (epoch 0 and let 0:
+    one schedule, the whole chip; ``n`` its size; launch when formed, done
+    when ``generate`` returned); ``dispatch_ms``, ``first_token_ms`` and
+    ``last_token_ms`` are ``ModelRunner.generate``'s host stamps.
+    """
+
+    span: BatchSpan
+    id: int
+    prompt_len: int
+    steps: int
+    request_ids: tuple[int, ...]
+    dispatch_ms: float
+    first_token_ms: float
+    last_token_ms: float
+
+
+class SleepSpan(NamedTuple):
+    """The replay loop slept at ``start_ms`` to wake at ``due_ms`` (the next
+    arrival) and woke at ``woke_ms``."""
+
+    kind: str
+    start_ms: float
+    due_ms: float
+    woke_ms: float
+
+
+class HostSpan(NamedTuple):
+    """Host work named ``kind`` between ``start_ms`` and ``end_ms``."""
+
+    kind: str
+    start_ms: float
+    end_ms: float
+
+
+class ServedRequest(NamedTuple):
+    """One request of a served window (``id``: its index in the replayed
+    list); ``admitted_ms`` is when the loop queued it, ``batch`` the id of
+    the batch that answered it (-1 and NaN stamps: not admitted or not
+    answered)."""
+
+    id: int
+    model: str
+    due_ms: float
+    admitted_ms: float
+    batch: int
+    done_ms: float
+
+
+#: tag -> record type of the engine's event log, for validators and exporters
 SPAN_KINDS = {
     "batch": BatchSpan,
     "decode": DecodeSpan,
