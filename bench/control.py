@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     run.use_compile_cache(run.ROOT)
     for seed in (int(s) for s in args.seeds.split(",")):
         setup = run.build(bench, args.workload, seed)
-        reqs, _, _, error, _ = run.serve_window(setup, seed, args.seconds, None)
+        reqs, _, error, _ = run.serve_window(setup, seed, args.seconds, None)
         run.free(setup)
         readings = run.correctness(setup, reqs, seed, control=True)
         for model, g in readings.items():

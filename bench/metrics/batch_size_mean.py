@@ -1,4 +1,4 @@
-"""Requests per ``generate`` call, over the window's batches."""
+"""Requests per batch, over the window's batches."""
 
 
 def read(record, arg):

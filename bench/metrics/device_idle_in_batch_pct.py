@@ -1,5 +1,6 @@
-"""Share of the time inside ``generate`` spans in which no operation ran on
-the device (trace): host work between the steps of a batch."""
+"""Share of the time inside ``serve.batch/<model>`` spans in which no
+operation ran on the device (trace): host work between the steps of a
+batch."""
 
 
 def read(record, arg):

@@ -7,6 +7,6 @@ from bench.metrics._flops import window_flops
 def read(record, arg):
     if not record["batches"]:
         return None
-    span_s = max(record["seconds"], max(b["end_ms"] for b in record["batches"]) / 1e3)
+    span_s = max(record["seconds"], max(b["done_ms"] for b in record["batches"]) / 1e3)
     peak = record["chips"] * record["peaks"]["bf16_flops"]
     return 100.0 * window_flops(record) / (span_s * peak)

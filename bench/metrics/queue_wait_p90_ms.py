@@ -1,5 +1,6 @@
 """p90 of batch start minus due time over the requests that were served
-(replay loop: time a request waits in its model's queue)."""
+(replay loop: time a request waits in its model's queue); a request's
+batch and its launch are the program's window log's."""
 from bench.metrics._common import nearest_rank
 
 

@@ -14,13 +14,18 @@ serving layers (``repro.launch.serve``):
      rates and the configuration's fixed SLOs; batch caps from the
      placement, as ``serve.run`` takes them;
   3. the window: the cell's traffic (``bench.traffic``) for ``--seconds``,
-     served by ``serve.replay``.  Each ``generate`` call is a span
-     (``jax.profiler.TraceAnnotation("generate/<model>")``); a batch that
-     would start after the window's end (``"stop": "window"``), or a minute
-     after it (``"stop": "drain"``), is refused;
-  4. ``--trace 1`` records the profiler trace of the window and reports the
-     per-layer metrics, ``--trace 0`` the end-to-end ones; each metric is
-     read by its own file in ``bench/metrics``;
+     served by ``serve.replay``, which logs what it did in the program's
+     recorder (``repro.obs.served.RECORDER``): requests, batches and their
+     spans, on the window's one clock.  The harness calls no
+     ``ModelRunner`` method in the window; its one hook
+     (``Watch.on_launch``) runs right before each batch starts, starts the
+     profiler where the trace begins, and refuses a batch that would
+     start after the window's end (``"stop": "window"``), or a minute
+     after it (``"stop": "drain"``);
+  4. ``--trace 1`` records the profiler trace of the window's last
+     ``TRACE_S`` seconds and reports the per-layer metrics, ``--trace 0``
+     the end-to-end ones; each metric is read by its own file in
+     ``bench/metrics``;
   5. once the window has closed, the peak memory is read and the program's
      state freed, served tokens are checked against the plain float32
      reference (``bench.check``).
@@ -38,6 +43,7 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
@@ -185,56 +191,88 @@ def plan(runners, slo_ms: dict, rates: dict, device):
     return caps, lat, profiles, placement
 
 
-class Recorder:
-    """Spans of the ``generate`` calls of one window, its deadline, and the
-    profiler trace of its last ``TRACE_S`` seconds.
+class Launches(list):
+    """A window's ``forms`` log that calls ``hook(ms)`` once each form has
+    entered it, with the form's end in ms since the window's 0.
 
-    The trace starts at the first batch due at or after ``trace_from_s``
-    (before the window, where that is 0), so that no export stalls the
-    window: it stops once the window has closed.  Its ``window`` span
-    brackets the traced part.
+    ``serve.replay`` logs a batch's ``serve.form`` span right before the
+    batch starts (before its ``serve.batch/<model>`` span opens), so the
+    hook runs where work for newly formed requests is about to start.  An
+    exception from the hook leaves ``replay`` there: the formed batch never
+    starts, and no batch of it enters the log.
     """
 
-    def __init__(self, deadline_s: float, trace_dir: str | None = None,
-                 trace_from_s: float = 0.0):
-        self.deadline_s = deadline_s
-        self.trace_dir, self.trace_from_s = trace_dir, trace_from_s
-        self.t0 = 0.0
-        self.batches: list[dict] = []
+    def __init__(self, hook):
+        super().__init__()
+        self.hook = hook
+
+    def append(self, span) -> None:
+        super().append(span)
+        self.hook(span.end_ms)
+
+
+class Watch:
+    """The harness's hook on one window of ``recorder``: its deadline, and
+    the profiler trace of its last ``TRACE_S`` seconds.
+
+    The trace starts at the first batch due to start at or after
+    ``trace_from_ms`` (before the window, where that is 0), so that no
+    export stalls the window: it stops once the window has closed.  Its
+    ``window`` span brackets the traced part.
+    """
+
+    def __init__(self, recorder, deadline_ms: float, trace_dir: str | None = None,
+                 trace_from_ms: float = 0.0):
+        self.recorder, self.deadline_ms = recorder, deadline_ms
+        self.trace_dir, self.trace_from_ms = trace_dir, trace_from_ms
         self.traced_from = None     # index of the first traced batch
         self._span = None
 
-    def start_trace(self, now_s: float) -> None:
-        if self.trace_dir and self._span is None and now_s >= self.trace_from_s:
+    @contextlib.contextmanager
+    def on_launch(self):
+        """``launch`` right before each batch of the ``serve.replay``
+        windows opened inside starts (``Launches``); cleared once they have
+        closed.
+
+        The program has no hook of its own: this one rides on the window
+        log that ``serve.replay`` writes, and changes nothing the loop does.
+        """
+        recorder = self.recorder
+        open_window = recorder.open_window
+
+        def opened(reqs):
+            window = open_window(reqs)
+            window.forms = Launches(self.launch)
+            return window
+
+        recorder.open_window = opened
+        try:
+            yield
+        finally:
+            del recorder.open_window
+            if recorder.window is not None:
+                recorder.window.forms = list(recorder.window.forms)
+
+    def launch(self, ms: float) -> None:
+        """A batch is about to start ``ms`` after the window's 0."""
+        if ms >= self.deadline_ms:
+            raise WindowClosed
+        self.start_trace(ms)
+
+    def start_trace(self, ms: float) -> None:
+        if self.trace_dir and self._span is None and ms >= self.trace_from_ms:
             jax.profiler.start_trace(self.trace_dir)
             self._span = jax.profiler.TraceAnnotation("window")
             self._span.__enter__()
-            self.traced_from = len(self.batches)
+            window = self.recorder.window
+            self.traced_from = (len(window.batches) if window is not None
+                                and window.open else 0)
 
     def stop_trace(self) -> None:
         if self._span is not None:
             self._span.__exit__(None, None, None)
             jax.profiler.stop_trace()
             self._span = None
-
-    def wrap(self, runner) -> None:
-        inner = runner.generate
-
-        def generate(prompts, n_new):
-            start = time.perf_counter() - self.t0
-            if start >= self.deadline_s:
-                raise WindowClosed
-            self.start_trace(start)
-            with jax.profiler.TraceAnnotation(f"generate/{runner.name}"):
-                out = inner(prompts, n_new)
-            self.batches.append({
-                "model": runner.name, "start_ms": start * 1e3,
-                "end_ms": (time.perf_counter() - self.t0) * 1e3,
-                "size": int(prompts.shape[0]), "prompt_len": int(prompts.shape[1]),
-                "steps": int(n_new)})
-            return out
-
-        runner.generate = generate
 
 
 #: Seconds of each traced run's window that the profiler records: its end.
@@ -243,66 +281,63 @@ TRACE_S = 10.0
 
 def serve_window(setup: Setup, seed: int, seconds: float, trace_dir: str | None,
                  traffic_spec: dict | None = None, caps: dict | None = None):
-    """Serve the cell's traffic for one window.  Returns (requests, batches,
-    window seconds, error, index of the first traced batch): ``error``
-    names what stopped the window early."""
+    """Serve the cell's traffic for one window.  Returns (requests, the
+    program's window log, error, index of the first traced batch):
+    ``error`` names what stopped the window early."""
     from repro.launch import serve
+    from repro.obs.served import RECORDER
 
     spec = traffic_spec or setup.traffic
     reqs = [serve.ServeRequest(model=m, arrival_ms=t, slo_ms=slo, prompt=p,
                                max_new=g)
             for m, t, p, g, slo in traffic.requests(
                 spec, setup.config["models"], seed, seconds)]
-    deadline = seconds + (DRAIN_S if spec["stop"] == "drain" else 0.0)
-    rec = Recorder(deadline, trace_dir, seconds - TRACE_S)
-    for r in setup.runners:
-        rec.wrap(r)
+    deadline_s = seconds + (DRAIN_S if spec["stop"] == "drain" else 0.0)
+    watch = Watch(RECORDER, deadline_s * 1e3, trace_dir, (seconds - TRACE_S) * 1e3)
     error = None
-    rec.start_trace(0.0)
+    watch.start_trace(0.0)
     try:
-        rec.t0 = time.perf_counter()
-        serve.replay(setup.runners, reqs, caps or setup.caps)
+        with watch.on_launch():
+            serve.replay(setup.runners, reqs, caps or setup.caps)
     except WindowClosed:
         pass
     except FloatingPointError as e:   # replay's check of the logits
         error = str(e)
     finally:
-        window_s = time.perf_counter() - rec.t0
-        rec.stop_trace()
-        for r in setup.runners:
-            del r.generate
-    return reqs, rec.batches, window_s, error, rec.traced_from
+        watch.stop_trace()
+    return reqs, RECORDER.window, error, watch.traced_from
 
 
-def request_rows(reqs, batches, stop: str) -> list[dict]:
-    """Each request the window attempted, with the start of its batch.
+def batch_rows(reqs, window) -> list[dict]:
+    """The window's batches, in the order they ran, with their requests'
+    own generation lengths (``max_new``)."""
+    return [{"id": b.id, "model": b.span.model, "launch_ms": b.span.launch_ms,
+             "done_ms": b.span.done_ms, "size": b.span.n,
+             "prompt_len": b.prompt_len, "steps": b.steps,
+             "request_ids": b.request_ids,
+             "max_new": [reqs[i].max_new for i in b.request_ids]}
+            for b in window.batches]
 
-    A request's batch is the last batch of its model that started before
-    the request's completion; ``stop: window`` attempts only the requests
-    whose batch started.
-    """
-    starts: dict[str, list] = {}
-    for b in batches:
-        starts.setdefault(b["model"], []).append(b)
+
+def request_rows(reqs, window, stop: str) -> list[dict]:
+    """Each request the window attempted, with the launch of the batch that
+    answered it (the window's own link); ``stop: window`` attempts only the
+    requests whose batch started."""
     rows = []
-    for r in reqs:
-        batch = None
-        if r.completion_ms is not None:
-            mine = [b for b in starts.get(r.model, []) if b["start_ms"] <= r.completion_ms]
-            batch = mine[-1]
-            batch.setdefault("max_new", []).append(r.max_new)
-        elif stop == "window":
+    for r, bid in zip(reqs, window.batch):
+        if bid < 0 and stop == "window":
             continue
         served = None if r.output is None else len(r.output)
         rows.append({"model": r.model, "due_ms": r.arrival_ms,
                      "done_ms": r.completion_ms, "slo_ms": r.slo_ms,
                      "prompt_len": len(r.prompt), "max_new": r.max_new,
                      "served": served,
-                     "batch_start_ms": None if batch is None else batch["start_ms"]})
+                     "batch_start_ms": (None if bid < 0
+                                        else window.batches[bid].span.launch_ms)})
     return rows
 
 
-def record_of(setup: Setup, rows, batches, seconds, window_s, setup_s,
+def record_of(setup: Setup, rows, batches, seconds, setup_s,
               reduced_trace) -> dict:
     """What the metric readers read."""
     for b in batches:
@@ -313,7 +348,7 @@ def record_of(setup: Setup, rows, batches, seconds, window_s, setup_s,
         models[r.name] = {"sizes": m["sizes"], "prefill_ms": dict(r.prefill_ms),
                           "decode_ms": dict(r.decode_ms),
                           "latency_ms": dict(r.latency_ms)}
-    return {"seconds": seconds, "window_s": window_s, "setup_s": setup_s,
+    return {"seconds": seconds, "setup_s": setup_s,
             "chips": len(setup.devices),
             "peaks": peaks.peaks(setup.devices[0].device_kind),
             "requests": rows, "batches": batches, "models": models,
@@ -355,22 +390,24 @@ def run(root: str, cell_name: str, seed: int, seconds: float, trace: bool) -> di
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
         setup_s = time.perf_counter() - PROCESS_START
-        reqs, batches, window_s, error, traced_from = serve_window(
-            setup, seed, seconds, trace_dir)
+        reqs, window, error, traced_from = serve_window(setup, seed, seconds,
+                                                        trace_dir)
         memory = peak_bytes(setup.devices)
         reduced = None
         if trace:
             t0 = time.perf_counter()
             t = trace_mod.load(trace_mod.find(trace_dir))
-            reduced = trace_mod.reduce(t, [d.id for d in setup.devices])
+            reduced = trace_mod.reduce(t, [d.id for d in setup.devices],
+                                       trace_mod.late_wakes(window, t.start_ns))
             reduced["first_batch"] = traced_from
             print(f"trace: {reduced['events']} device events read in "
                   f"{time.perf_counter() - t0:.1f} s", file=sys.stderr)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    rows = request_rows(reqs, batches, setup.traffic["stop"])
-    record = record_of(setup, rows, batches, seconds, window_s, setup_s, reduced)
+    rows = request_rows(reqs, window, setup.traffic["stop"])
+    record = record_of(setup, rows, batch_rows(reqs, window), seconds, setup_s,
+                       reduced)
     metrics = {}
     for m in bench.cell_metrics(cell_name, trace):
         value = bench.read(m.name, record)

@@ -65,9 +65,9 @@ def main(argv=None) -> int:
         rates = {m: rate for m in models}
         spec = dict(setup.traffic, rates_req_s=rates, plan_rates_req_s=rates)
         caps, _, _, placement = run.plan(setup.runners, slo, rates, setup.devices[0])
-        reqs, batches, _, error, _ = run.serve_window(setup, args.seed + k, args.seconds,
-                                                   None, spec, caps)
-        rows = run.request_rows(reqs, batches, spec["stop"])
+        reqs, window, error, _ = run.serve_window(setup, args.seed + k, args.seconds,
+                                                  None, spec, caps)
+        rows = run.request_rows(reqs, window, spec["stop"])
         print(json.dumps({"rate_req_s": rate, "caps": caps,
                           "schedulable": placement.schedulable, "error": error,
                           **summarize(rows, args.seconds)}), flush=True)

@@ -3,21 +3,24 @@
 The trace is the ``.xplane.pb`` that ``jax.profiler`` writes.  Device
 planes are ``/device:TPU:<n>``; on each, the ``XLA Modules`` line holds one
 event per program run (``jit_decode(…)``) and the ``XLA Ops`` line one per
-operation.  The harness's own spans (``jax.profiler.TraceAnnotation``:
-``window``, ``generate/<model>``) are events on a host plane, on the same
-clock as the device's.
+operation.  Host spans (``jax.profiler.TraceAnnotation``) are events on a
+host plane, on the same clock as the device's: the harness's ``window``,
+and the program's ``serve.sleep``, ``serve.form`` and
+``serve.batch/<model>`` (``repro.obs.served``).
 
   * busy: the union of a device's program runs inside the window;
+  * in batches: the part of that union inside ``serve.batch/`` spans;
   * programs: each program run of the window, in order, with its device
     time;
   * all-reduce: time of the operations named ``all-reduce…`` (the
     collective itself, its start and done halves, or a fusion around it);
-  * breakdown: the operations that took most time, and the longest idle
-    gaps, labelled by the harness span open at the time (``wait`` outside
-    every ``generate`` span).
+  * breakdown: the operations that took most time, each named by the model
+    whose batch ran it, and the longest idle gaps, each labelled by what
+    the program was doing at its middle (``Labels``).
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import glob
@@ -27,7 +30,8 @@ import re
 import numpy as np
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-SPAN_PREFIXES = ("window", "generate/")
+SPAN_PREFIXES = ("window", "serve.sleep", "serve.form", "serve.batch/")
+BATCH = "serve.batch/"
 MODULES, OPS = "XLA Modules", "XLA Ops"
 
 
@@ -40,7 +44,8 @@ class Device:
 @dataclasses.dataclass
 class Trace:
     devices: dict[int, Device]
-    spans: list  # (start_ns, end_ns, name) of the harness's host spans
+    spans: list      # (start_ns, end_ns, name) of the host spans read
+    start_ns: int    # the profile's start (its ``profile_start_time``)
 
 
 def find(directory: str) -> str:
@@ -60,7 +65,9 @@ def load(path: str, ops_of: int = 0) -> Trace:
     data = ProfileData.from_file(path)
     devices: dict[int, Device] = {}
     spans = []
+    start_ns = 0
     for plane in data.planes:
+        start_ns = dict(plane.stats).get("profile_start_time", start_ns)
         m = DEVICE_PLANE.match(plane.name)
         if m:
             dev = devices.setdefault(int(m.group(1)), Device())
@@ -81,7 +88,7 @@ def load(path: str, ops_of: int = 0) -> Trace:
     if not devices:
         raise ValueError(f"{path}: no TPU device plane in the trace")
     spans.sort()
-    return Trace(devices, spans)
+    return Trace(devices, spans, start_ns)
 
 
 def _union(intervals: list, lo: float, hi: float) -> np.ndarray:
@@ -108,25 +115,36 @@ def _covered(union: np.ndarray, lo: float, hi: float) -> float:
                          0, None).sum())
 
 
-def reduce(trace: Trace, devices: list[int] | None = None) -> dict:
-    """The numbers the metrics read, over the trace's ``window`` span."""
+def late_wakes(window, start_ns: int) -> list:
+    """The intervals, on a trace's clock (ns after ``start_ns``), in which
+    the replay loop slept past the next due time: each ``serve.sleep`` of
+    the program's ``window`` from its ``due_ms`` to its ``woke_ms``."""
+    return [(window.trace_ns(s.due_ms, start_ns), window.trace_ns(s.woke_ms, start_ns))
+            for s in window.sleeps if s.woke_ms > s.due_ms]
+
+
+def reduce(trace: Trace, devices: list[int] | None = None,
+           late: list = ()) -> dict:
+    """The numbers the metrics read, over the trace's ``window`` span;
+    ``late`` as ``late_wakes`` gives them."""
     windows = [s for s in trace.spans if s[2] == "window"]
     if len(windows) != 1:
         raise ValueError(f"expected one 'window' span, found {len(windows)}")
     lo, hi, _ = windows[0]
     ids = sorted(devices if devices is not None else trace.devices)
-    gens = [s for s in trace.spans if s[2].startswith("generate/")]
+    program = [s for s in trace.spans if s[2] != "window"]
+    batches = [s for s in program if s[2].startswith(BATCH)]
     busy_s, in_spans_s = [], []
     for i in ids:
         u = _union(trace.devices[i].modules, lo, hi)
         busy_s.append(_covered(u, lo, hi) / 1e9)
-        in_spans_s.append(sum(_covered(u, s, e) for s, e, _ in gens) / 1e9)
+        in_spans_s.append(sum(_covered(u, s, e) for s, e, _ in batches) / 1e9)
         if i == ids[0]:
             union0 = u
     dev0 = trace.devices[ids[0]]
     # program runs of the window, in the order the device ran them: the
     # device's clock may stand a fraction of a millisecond off the host's,
-    # so runs are matched to the harness's batches by order, not by time.
+    # so runs are matched to the window's batches by order, not by time.
     programs = [[name.split("(")[0], (e - s) / 1e9]
                 for s, e, name in sorted(dev0.modules) if lo - 1e6 <= s < hi]
     allreduce = sum(min(e, hi) - max(s, lo) for s, e, n in dev0.ops
@@ -135,13 +153,13 @@ def reduce(trace: Trace, devices: list[int] | None = None) -> dict:
         "window_s": (hi - lo) / 1e9,
         "busy_s": float(np.mean(busy_s)),
         "busy_in_spans_s": float(np.mean(in_spans_s)),
-        "spans_s": sum(e - s for s, e, _ in gens) / 1e9,
+        "spans_s": sum(e - s for s, e, _ in batches) / 1e9,
         "busy_s_dev0": busy_s[0],
         "allreduce_s_dev0": allreduce,
         "programs": programs,
         "events": sum(len(trace.devices[i].ops) + len(trace.devices[i].modules)
                       for i in ids),
-        "breakdown": breakdown(trace, union0, gens, lo, hi),
+        "breakdown": breakdown(trace, union0, Labels(program, late), lo, hi),
     }
 
 
@@ -154,15 +172,36 @@ def op_name(event_name: str) -> str:
 CONTAINERS = ("while", "conditional", "call")
 
 
-def _label(t: float, gens: list) -> str:
-    return next((n for s, e, n in gens if s <= t < e), "wait")
+class Labels:
+    """What the program was doing at a time on the trace's clock: the name
+    of its span open then (``serve.sleep``, ``serve.form``,
+    ``serve.batch/<model>``, which never overlap), ``serve.sleep.late``
+    inside a sleep past its due time, and ``loop`` outside every span."""
+
+    def __init__(self, spans: list, late: list = ()):
+        self.spans = sorted(spans)
+        self.starts = [s for s, _, _ in self.spans]
+        self.late = sorted(late)
+        self.late_starts = [s for s, _ in self.late]
+
+    def __call__(self, t: float) -> str:
+        k = bisect.bisect_right(self.starts, t) - 1
+        if k < 0 or t >= self.spans[k][1]:
+            return "loop"
+        name = self.spans[k][2]
+        if name == "serve.sleep":
+            j = bisect.bisect_right(self.late_starts, t) - 1
+            if j >= 0 and t < self.late[j][1]:
+                return "serve.sleep.late"
+        return name
 
 
-def breakdown(trace: Trace, union0: np.ndarray, gens: list, lo: float,
+def breakdown(trace: Trace, union0: np.ndarray, label: Labels, lo: float,
               hi: float, top: int = 10) -> dict:
     """The device operations that took most time on the first device, each
-    named ``<span>:<program>/<operation>``, and the longest idle gaps, each
-    named by the harness span open at its middle."""
+    named ``<model>:<program>/<operation>`` by the batch that ran it, and
+    the longest idle gaps, each named by what the program was doing at its
+    middle."""
     dev0 = trace.devices[min(trace.devices)]
     mods = sorted(dev0.modules)
     mod_starts = np.array([m[0] for m in mods], dtype=np.float64)
@@ -173,10 +212,10 @@ def breakdown(trace: Trace, union0: np.ndarray, gens: list, lo: float,
             continue
         k = int(np.searchsorted(mod_starts, s, side="right")) - 1
         program = mods[k][2].split("(")[0] if k >= 0 and s < mods[k][1] else "?"
-        span = _label(s, gens).removeprefix("generate/")
-        per_op[f"{span}:{program}/{op}"] += (min(e, hi) - max(s, lo)) / 1e9
+        model = label(s).removeprefix(BATCH)
+        per_op[f"{model}:{program}/{op}"] += (min(e, hi) - max(s, lo)) / 1e9
     edges = np.concatenate([[lo], union0.ravel(), [hi]]).reshape(-1, 2)
     gaps = sorted(((e - s, s) for s, e in edges if e > s), reverse=True)[:top]
     return {"device_ops": [[n, t] for n, t in per_op.most_common(top)],
-            "idle_gaps": [[_label(start + length / 2, gens), length / 1e9]
+            "idle_gaps": [[label(start + length / 2), length / 1e9]
                           for length, start in gaps]}

@@ -66,3 +66,57 @@ def write_smoke_bench(root: str) -> None:
             wl.append("smoke-backlog")
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
+
+
+def steer_to_cpu(monkeypatch) -> None:
+    """Let the harness serve on the CPU: its look for a chip and its table
+    of peaks are steered here, in the test."""
+    import jax
+
+    from bench import peaks, run
+    monkeypatch.setattr(run, "accelerator_devices", lambda chips: jax.devices()[:chips])
+    monkeypatch.setitem(peaks.PEAKS, "cpu", {"bf16_flops": 1e12, "hbm_bytes_s": 1e11,
+                                             "hbm_bytes": 1e10})
+
+
+#: The stub loop's virtual costs: forming a batch, and each of its tokens.
+FORM_MS, TOKEN_MS = 2.0, 10.0
+
+
+def stub_replay(runners, reqs, caps):
+    """A replay loop that never calls ``ModelRunner.generate``, on a virtual
+    clock: each request alone, in order of due time, formed in ``FORM_MS``
+    from the later of its due time and the previous batch's end, then
+    ``TOKEN_MS`` a token; its tokens are zeros.  It logs in the program's
+    recorder as ``serve.replay`` does, a batch's form right before the
+    batch (where the harness's hook runs)."""
+    import numpy as np
+
+    from repro.obs.served import RECORDER
+    from repro.obs.spans import BatchSpan, HostSpan, ServedBatch
+
+    log = RECORDER.open_window(reqs)
+    try:
+        t = 0.0
+        for i, r in enumerate(reqs):
+            t = log.admitted_ms[i] = max(t, r.arrival_ms)
+            launch = t + FORM_MS
+            log.forms.append(HostSpan("serve.form", t, launch))
+            t = launch + TOKEN_MS * r.max_new
+            bid = len(log.batches)
+            log.batches.append(ServedBatch(
+                BatchSpan("batch", 0, 0, launch, t, r.model, 1), bid,
+                len(r.prompt), r.max_new, (i,), launch, launch + TOKEN_MS, t))
+            r.output, r.completion_ms = np.zeros(r.max_new, np.int32), t
+            log.batch[i], log.done_ms[i] = bid, t
+    finally:
+        log.open = False
+
+
+def stub_launches(reqs) -> list[float]:
+    """Each batch's launch under ``stub_replay``, served in full."""
+    out, t = [], 0.0
+    for r in reqs:
+        out.append(max(t, r.arrival_ms) + FORM_MS)
+        t = out[-1] + TOKEN_MS * r.max_new
+    return out

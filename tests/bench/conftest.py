@@ -1,19 +1,12 @@
 """Fixtures of the benchmark's CPU tests."""
 import pytest
 
-from bench_helpers import write_smoke_bench
+from bench_helpers import steer_to_cpu, write_smoke_bench
 
 
 @pytest.fixture
 def on_cpu(monkeypatch):
-    """Let the harness serve on the CPU: its look for a chip and its table
-    of peaks are steered here, in the test."""
-    import jax
-
-    from bench import peaks, run
-    monkeypatch.setattr(run, "accelerator_devices", lambda chips: jax.devices()[:chips])
-    monkeypatch.setitem(peaks.PEAKS, "cpu", {"bf16_flops": 1e12, "hbm_bytes_s": 1e11,
-                                             "hbm_bytes": 1e10})
+    steer_to_cpu(monkeypatch)
 
 
 @pytest.fixture(scope="module")

@@ -94,7 +94,7 @@ def test_a_broken_timed_path_reads_not_correct(fault, smoke_root, on_cpu, monkey
 
 def test_the_float8_control_fails_the_limit_the_program_meets(smoke_root, on_cpu):
     setup = run.build(run.Benchmark(smoke_root), "smoke-steady", SEED)
-    reqs, _, _, error, _ = run.serve_window(setup, SEED, 3.0, None)
+    reqs, _, error, _ = run.serve_window(setup, SEED, 3.0, None)
     run.free(setup)
     readings = run.correctness(setup, reqs, SEED, control=True)
     assert error is None and set(readings) == {"chatglm3-6b", "mamba2-780m"}

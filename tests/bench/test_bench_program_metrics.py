@@ -1,17 +1,17 @@
 """The readers of the program's own spans and counters (``repro.obs.served``):
 hand-computed values on a hand-built log, nothing where the program has no
-window or no recorder, and a consistent log when the harness stops a
-window early."""
+window or no recorder, a consistent log when the harness stops a window
+early, and the parent harness's readings on the same window."""
 import math
 import sys
+import time
 
 import pytest
 
-from bench_helpers import ROOT
+from bench_helpers import ROOT, steer_to_cpu
 
 from bench import run
 from bench.spec import Benchmark
-from bench.trace import SPAN_PREFIXES
 from repro.obs import served
 from repro.obs.spans import BatchSpan, HostSpan, ServedBatch, SleepSpan
 
@@ -98,15 +98,69 @@ def test_a_program_without_the_recorder_reads_nothing(bench, monkeypatch, name):
     assert bench.read(name, RECORD) is None
 
 
-def test_an_early_stop_leaves_the_log_consistent(smoke_root, on_cpu):
-    """``smoke-backlog`` stops at its window's end: the harness's wrapper
-    raises ``WindowClosed`` inside the program's ``serve.batch`` span."""
-    setup = run.build(run.Benchmark(smoke_root), "smoke-backlog", 5)
-    reqs, batches, window_s, error, _ = run.serve_window(setup, 5, 1.0, None)
-    w = served.RECORDER.window
+def parent_generate(runner, log: list):
+    """The parent harness's wrapper around ``runner.generate``, kept as the
+    reference: each call's start and end, on the window's clock."""
+    inner = runner.generate
+
+    def generate(prompts, n_new):
+        t0_s = served.RECORDER.window.t0_s
+        start = (time.perf_counter() - t0_s) * 1e3
+        out = inner(prompts, n_new)
+        log.append({"model": runner.name, "start_ms": start,
+                    "end_ms": (time.perf_counter() - t0_s) * 1e3,
+                    "size": int(prompts.shape[0]),
+                    "prompt_len": int(prompts.shape[1]), "steps": int(n_new)})
+        return out
+
+    return generate
+
+
+def parent_request_rows(reqs, batches, stop: str) -> list[dict]:
+    """The parent's ``request_rows``, kept as the reference: a request's
+    batch is the last batch of its model that started before the request's
+    completion."""
+    starts: dict[str, list] = {}
+    for b in batches:
+        starts.setdefault(b["model"], []).append(b)
+    rows = []
+    for r in reqs:
+        batch = None
+        if r.completion_ms is not None:
+            batch = [b for b in starts.get(r.model, [])
+                     if b["start_ms"] <= r.completion_ms][-1]
+        elif stop == "window":
+            continue
+        rows.append({"due_ms": r.arrival_ms, "batch": batch,
+                     "batch_start_ms": None if batch is None else batch["start_ms"]})
+    return rows
+
+
+@pytest.fixture(scope="module")
+def stopped(smoke_root):
+    """``smoke-backlog`` served by ``serve.replay`` for 1 s and stopped at
+    the window's end by the harness's hook; beside the program's log, the
+    batches as the parent's wrapper around ``generate`` stamped them."""
+    with pytest.MonkeyPatch.context() as mp:
+        steer_to_cpu(mp)
+        setup = run.build(run.Benchmark(smoke_root), "smoke-backlog", 5)
+        wrapped: list[dict] = []
+        for r in setup.runners:
+            mp.setattr(r, "generate", parent_generate(r, wrapped))
+        reqs, w, error, _ = run.serve_window(setup, 5, 1.0, None)
+    return setup, reqs, w, error, wrapped
+
+
+def test_an_early_stop_leaves_the_log_consistent(stopped, on_cpu, monkeypatch):
+    """``smoke-backlog`` stops at its window's end: the harness's hook
+    raises ``WindowClosed`` right before the program's ``serve.batch`` span
+    would open."""
+    setup, reqs, w, error, wrapped = stopped
+    monkeypatch.setattr(served.RECORDER, "window", w)
     assert error is None and not w.open and w.compiles == 0
-    assert len(w.batches) == len(batches) > 0
+    assert len(w.batches) == len(wrapped) > 0
     assert len(w.forms) == len(w.batches) + 1      # the batch refused
+    assert type(w.forms) is list                   # the hook is cleared
     answered = [r for r in w.requests() if r.batch >= 0]
     assert len(answered) == sum(b.span.n for b in w.batches)
     assert all(not math.isnan(r.admitted_ms) for r in w.requests())
@@ -117,15 +171,45 @@ def test_an_early_stop_leaves_the_log_consistent(smoke_root, on_cpu):
         b = w.batches[r.batch]
         assert r.id in b.request_ids and reqs[r.id].completion_ms == r.done_ms
         assert r.due_ms <= r.admitted_ms <= b.span.launch_ms < b.first_token_ms
-    for ours, theirs in zip(w.batches, batches):
-        assert ours.span.model == theirs["model"]
-        assert ours.span.launch_ms <= theirs["start_ms"] + 1.0
-    rows = run.request_rows(reqs, batches, "window")
-    record = run.record_of(setup, rows, batches, 1.0, window_s, 1.0, None)
+    rows = run.request_rows(reqs, w, "window")
+    record = run.record_of(setup, rows, run.batch_rows(reqs, w), 1.0, 1.0, None)
     got = {n: setup.bench.read(n, record) for n in READERS}
     assert got["compiles_in_window"] == 0 and got["replay_wake_late_max_ms"] is None
     assert 0 < got["setup_programs_s"] and got["first_token_p90_ms"] > 0
     assert got[f"decode_step_served_ms.{GLM}"] > 0
     spans = [s.kind for m in record["models"] for s in served.RECORDER.setup[m].values()]
-    assert len(spans) == 6 and not [n for n in spans if n.startswith(SPAN_PREFIXES)]
+    assert len(spans) == 6 and "window" not in spans
 
+
+def test_the_window_reads_as_the_parent_harness_read(stopped, on_cpu, monkeypatch):
+    """Each request's batch, by the window's id, is the one the parent's
+    rule finds; the batch readers agree with the parent's stamps."""
+    setup, reqs, w, _, wrapped = stopped
+    monkeypatch.setattr(served.RECORDER, "window", w)
+    batches = run.batch_rows(reqs, w)
+    rows = run.request_rows(reqs, w, "window")
+    by_launch = [dict(b, start_ms=b["launch_ms"]) for b in batches]
+    parent = parent_request_rows(reqs, by_launch, "window")
+    attempted = [i for i, b in enumerate(w.batch) if b >= 0]
+    assert len(rows) == len(parent) == len(attempted) > 0
+    assert [old["batch"]["id"] for old in parent] == [w.batch[i] for i in attempted]
+    for ours, theirs in zip(batches, wrapped):
+        assert (ours["model"], ours["size"], ours["prompt_len"], ours["steps"]) == (
+            theirs["model"], theirs["size"], theirs["prompt_len"], theirs["steps"])
+        assert 0 <= theirs["start_ms"] - ours["launch_ms"] < 1.0
+        assert 0 <= ours["done_ms"] - theirs["end_ms"] < 1.0
+    record = run.record_of(setup, rows, batches, 1.0, 1.0, None)
+    read = setup.bench.read
+    assert read("queue_wait_p90_ms", record) == pytest.approx(
+        read("queue_wait_p90_ms", {"requests": parent}), abs=0.01)
+    stamped = [dict(ours, launch_ms=theirs["start_ms"], done_ms=theirs["end_ms"])
+               for ours, theirs in zip(batches, wrapped)]
+    old = run.record_of(setup, parent_request_rows(reqs, [
+        dict(b, start_ms=b["launch_ms"]) for b in stamped], "window"),
+        stamped, 1.0, 1.0, None)
+    for name in ("queue_wait_p90_ms", "mfu_busy"):
+        assert read(name, record) == pytest.approx(read(name, old), rel=0.01), name
+    # an error share moves by the stamps' gap over the batch's time: up to
+    # 0.1 ms over batches of 20-90 ms at smoke size, about 0.3 points
+    assert read("sched_latency_err_pct", record) == pytest.approx(
+        read("sched_latency_err_pct", old), abs=0.5)

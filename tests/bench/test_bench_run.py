@@ -33,15 +33,16 @@ def test_a_smoke_run_prints_the_contracts_keys(smoke_root, on_cpu, capsys):
 
 def test_the_backlog_cell_stops_at_the_end_of_its_window(smoke_root, on_cpu):
     setup = run.build(run.Benchmark(smoke_root), "smoke-backlog", 3)
-    reqs, batches, window_s, error, _ = run.serve_window(setup, 3, 2.0, None)
-    rows = run.request_rows(reqs, batches, "window")
+    reqs, window, error, _ = run.serve_window(setup, 3, 2.0, None)
+    rows = run.request_rows(reqs, window, "window")
+    batches = run.batch_rows(reqs, window)
     assert error is None and batches
-    assert all(b["start_ms"] < 2e3 for b in batches)
-    assert window_s < 2.0 + 1.0
+    assert all(b["launch_ms"] < 2e3 for b in batches)
+    assert max(b["done_ms"] for b in batches) < 2e3 + 1e3
     assert 0 < len(rows) < len(reqs) == 800
     assert len(rows) == sum(b["size"] for b in batches)
     assert all(r["served"] == r["max_new"] for r in rows)
-    record = run.record_of(setup, rows, batches, 2.0, window_s, 1.0, None)
+    record = run.record_of(setup, rows, batches, 2.0, 1.0, None)
     bench = setup.bench
     assert bench.read("throughput_tok_s", record) > 0
     assert bench.read("batch_size_mean", record) == pytest.approx(
