@@ -49,7 +49,7 @@ from repro.core.tpulets import load_catalog
 from repro.launch import sharding as shr
 from repro.launch.mesh import make_serving_mesh
 from repro.models.config import ModelConfig, round_up
-from repro.models.model import Model
+from repro.models.model import PAD, Model
 from repro.models.shard_ctx import mesh_context
 from repro.obs.served import RECORDER, Window
 from repro.obs.spans import BatchSpan, HostSpan, ServedBatch, SleepSpan
@@ -89,9 +89,10 @@ def logit_tol(cfg: ModelConfig) -> float:
 class ServeShapes:
     """Request shapes a server compiles for.
 
-    A batch holds requests of one prompt length (the decode cache carries
-    one length per batch); requests that stop earlier than the batch's
-    longest generation keep only their own tokens.
+    A batch of prompts of different lengths runs at its longest, one of
+    ``prompt_lens``, the others right-padded (the decode cache carries one
+    length per row); requests that stop earlier than the batch's longest
+    generation keep only their own tokens.
     """
 
     prompt_lens: tuple[int, ...] = (128, 256, 384, 512)
@@ -226,12 +227,13 @@ class ModelRunner:
     def generate(self, prompts: np.ndarray, n_new: int) -> Generation:
         """Greedy prefill plus ``n_new - 1`` decode steps for one batch.
 
-        Every step is queued before the first token comes to the host, so
-        the device never waits on the host inside a batch, however late
-        the host wakes; the first token's stamp is thus no earlier than
-        the last step's dispatch.  ``serve.prefill/<model>`` spans dispatch
-        to first token on the host, ``serve.decode/<model>`` first to last
-        token.
+        ``prompts`` rows may be right-padded with ``PAD`` to the batch's
+        prompt length: each row continues its own prompt.  Every step is
+        queued before the first token comes to the host, so the device
+        never waits on the host inside a batch, however late the host
+        wakes; the first token's stamp is thus no earlier than the last
+        step's dispatch.  ``serve.prefill/<model>`` spans dispatch to first
+        token on the host, ``serve.decode/<model>`` first to last token.
         """
         b, s = prompts.shape
         _, t_sh = self._tokens(b, s)
@@ -409,10 +411,11 @@ def replay(runners: Sequence[ModelRunner], reqs: list[ServeRequest],
            caps: dict[str, int]) -> None:
     """Serve ``reqs`` in real time, one batch at a time, model by model.
 
-    A batch takes the oldest queued request of a model and the queued
-    requests with its prompt length, up to the model's scheduled batch,
-    rounded down to a compiled bucket.  The recorder's window (a fresh one
-    per call) logs each step; a batch enters it once it has answered.
+    A batch takes a model's queued requests, oldest first, of any prompt
+    length, up to the model's scheduled batch rounded down to a compiled
+    bucket; its prompts are right-padded with ``PAD`` to the longest.  The
+    recorder's window (a fresh one per call) logs each step; a batch enters
+    it once it has answered.
     """
     queues: dict[str, list[int]] = {r.name: [] for r in runners}
     log = RECORDER.open_window(reqs)
@@ -432,14 +435,14 @@ def replay(runners: Sequence[ModelRunner], reqs: list[ServeRequest],
                     continue
                 with jax.profiler.TraceAnnotation("serve.form"):
                     t_form = time.perf_counter()
-                    s = len(reqs[q[0]].prompt)
-                    group = [i for i in q if len(reqs[i].prompt) == s]
-                    want = min(len(group), caps[runner.name])
+                    want = min(len(q), caps[runner.name])
                     b = max(x for x in runner.shapes.batch_buckets if x <= want)
-                    ids = group[:b]
-                    queues[runner.name] = [i for i in q if i not in ids]
+                    ids, queues[runner.name] = q[:b], q[b:]
                     batch = [reqs[i] for i in ids]
-                    prompts = np.stack([r.prompt for r in batch])
+                    row_lens = tuple(len(r.prompt) for r in batch)
+                    prompts = np.full((b, max(row_lens)), PAD, np.int32)
+                    for k, r in enumerate(batch):
+                        prompts[k, :len(r.prompt)] = r.prompt
                     n_new = max(r.max_new for r in batch)
                     t_formed = time.perf_counter()
                 log.forms.append(HostSpan("serve.form", log.ms(t_form),
@@ -454,8 +457,9 @@ def replay(runners: Sequence[ModelRunner], reqs: list[ServeRequest],
                 log.batches.append(ServedBatch(
                     BatchSpan("batch", 0, 0, log.ms(t_batch), t_done,
                               runner.name, b),
-                    bid, s, n_new, tuple(ids), log.ms(out.dispatch_s),
-                    log.ms(out.first_token_s), log.ms(out.done_s)))
+                    bid, prompts.shape[1], n_new, tuple(ids),
+                    log.ms(out.dispatch_s), log.ms(out.first_token_s),
+                    log.ms(out.done_s), row_lens))
                 for k, (i, r) in enumerate(zip(ids, batch)):
                     r.output = out.tokens[k, :r.max_new]
                     r.completion_ms = t_done

@@ -9,6 +9,9 @@ is jit-able.  Batches are dicts:
 
 Training loss is next-token cross-entropy (audio: per-frame CE against
 ``labels``); VLM masks the loss to text positions.
+
+Serving keeps one cache length per row (``cache["len"]``, shape (B,)):
+prompts of different lengths share a batch, right-padded with ``PAD``.
 """
 from __future__ import annotations
 
@@ -23,6 +26,10 @@ from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
 from repro.models.layers import embed_init, make_norm
 from repro.models.shard_ctx import constrain_act
+
+#: Token id that right-pads a prompt to its batch's longest; it embeds as
+#: token 0, and ``prefill`` reads each row's length from where it starts.
+PAD = -1
 
 
 class Model:
@@ -149,7 +156,7 @@ class Model:
                 *[one(kinds[0]) for _ in range(cfg.n_layers)])
         else:
             caches = [one(k) for k in kinds]
-        return {"layers": caches, "len": jnp.zeros((), jnp.int32)}
+        return {"layers": caches, "len": jnp.zeros((batch,), jnp.int32)}
 
     def cache_shapes(self, batch: int, max_len: int, *,
                      window: int | None = None):
@@ -159,21 +166,35 @@ class Model:
     # ---------------------------------------------------------- serving ----
 
     def prefill(self, params, batch, cache):
-        """Process a prompt, filling ``cache``.  Returns (last_logits, cache)."""
+        """Process a prompt, filling ``cache``.  Returns (last_logits, cache).
+
+        ``tokens`` rows may be right-padded with ``PAD``: each row's logits
+        are those of its own last position, its cache length its own, and
+        its recurrent state the state at its own last token.
+        """
         cfg = self.cfg
-        x, _ = self._embed_inputs(params, batch)
+        tokens = batch["tokens"]
+        x, _ = self._embed_inputs(
+            params, dict(batch, tokens=jnp.maximum(tokens, 0)))
         seq_len = x.shape[1]
+        # a vlm's patch prefix is never padded
+        lens = (seq_len - tokens.shape[1]
+                + jnp.sum(tokens != PAD, axis=1, dtype=jnp.int32))
         positions = jnp.broadcast_to(jnp.arange(seq_len), x.shape[:2])
         _, norm = make_norm(cfg.norm)
         x, new_layer_caches, _ = tfm.stack_apply_seq(
-            params["layers"], x, cfg, positions, caches=cache["layers"])
-        x = norm(params["final_norm"], x[:, -1:])
-        logits = self._head(params, x)
+            params["layers"], x, cfg, positions, caches=cache["layers"],
+            lens=lens)
+        x = jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)
+        logits = self._head(params, norm(params["final_norm"], x))
         return logits, {"layers": new_layer_caches,
-                        "len": cache["len"] + seq_len}
+                        "len": cache["len"] + lens}
 
     def decode_step(self, params, cache, tokens):
-        """One decode step.  tokens: (B, 1) int32 (audio: unsupported)."""
+        """One decode step.  tokens: (B, 1) int32 (audio: unsupported).
+
+        Each row attends, and writes its K/V, at its own cache length.
+        """
         cfg = self.cfg
         assert cfg.has_decoder, f"{cfg.name} is encoder-only"
         x = jnp.take(params["embed"]["tok"], tokens, axis=0)

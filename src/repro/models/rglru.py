@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
+from repro.models.ssm import conv_history
 
 CONV_K = 4
 C_EXP = 8.0
@@ -53,7 +54,7 @@ def _gates(params, xb):
     return a, beta * (i * xb)
 
 
-def _conv(params, x, conv_state=None):
+def _conv(params, x, conv_state=None, lens=None):
     k = CONV_K
     if conv_state is None:
         pad = jnp.zeros_like(x[:, : k - 1])
@@ -61,16 +62,23 @@ def _conv(params, x, conv_state=None):
         pad = conv_state
     xpad = jnp.concatenate([pad, x], axis=1)
     out = sum(xpad[:, i:i + x.shape[1]] * params["conv"][i] for i in range(k))
-    return out, xpad[:, -(k - 1):]
+    return out, conv_history(pad, x, lens)
 
 
-def rglru_apply(params, x, cfg: ModelConfig, state=None):
-    """x: (B, S, D) -> (B, S, D); state dict(conv, h) or None."""
+def rglru_apply(params, x, cfg: ModelConfig, state=None, lens=None):
+    """x: (B, S, D) -> (B, S, D); state dict(conv, h) or None.
+
+    lens: (B,) rows' own lengths in a right-padded batch, or None; a padded
+    position has a = 1 and no input, so it leaves h as it was.
+    """
     xb = x @ params["w_x"]                               # (B,S,W)
     conv_state = None if state is None else state["conv"]
-    xb, new_conv = _conv(params, xb, conv_state)
+    xb, new_conv = _conv(params, xb, conv_state, lens)
     xf = xb.astype(jnp.float32)
     a, b = _gates(params, xf)
+    if lens is not None:
+        live = (jnp.arange(x.shape[1]) < lens[:, None])[..., None]
+        a, b = jnp.where(live, a, 1.0), jnp.where(live, b, 0.0)
     if state is not None:
         # fold the carried state into the first step: b_0 += a_0 * h_prev
         b = b.at[:, 0].add(a[:, 0] * state["h"])

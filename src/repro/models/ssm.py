@@ -65,7 +65,21 @@ def _split_proj(params, x, cfg: ModelConfig):
     return z, xin, bmat, cmat, dt
 
 
-def _causal_conv(xin, conv_w, conv_state=None):
+def conv_history(pad, x, lens=None):
+    """The last ``k - 1`` inputs of each row of ``pad`` (B, k-1, W) followed
+    by ``x`` (B, S, W): those before the row's own length ``lens`` (B,), or
+    before ``S``.  Rows are read from ``x`` and ``pad`` themselves, so their
+    concatenation need not be written out."""
+    k1 = pad.shape[1]
+    if lens is None:
+        return jnp.concatenate([pad, x], axis=1)[:, -k1:]
+    idx = (lens[:, None] - k1 + jnp.arange(k1))[:, :, None]
+    from_x = jnp.take_along_axis(x, jnp.maximum(idx, 0), axis=1)
+    from_pad = jnp.take_along_axis(pad, jnp.clip(idx + k1, 0, k1 - 1), axis=1)
+    return jnp.where(idx >= 0, from_x, from_pad)
+
+
+def _causal_conv(xin, conv_w, conv_state=None, lens=None):
     """Depthwise causal conv along the sequence.  xin: (B, S, Di)."""
     k = conv_w.shape[0]
     if conv_state is None:
@@ -74,7 +88,7 @@ def _causal_conv(xin, conv_w, conv_state=None):
         pad = conv_state  # (B, k-1, Di)
     xpad = jnp.concatenate([pad, xin], axis=1)
     out = sum(xpad[:, i:i + xin.shape[1]] * conv_w[i] for i in range(k))
-    new_state = xpad[:, -(k - 1):]
+    new_state = conv_history(pad, xin, lens)
     return jax.nn.silu(out.astype(jnp.float32)).astype(xin.dtype), new_state
 
 
@@ -147,19 +161,25 @@ def ssd_chunked(xh, dt, a, bmat, cmat, h0=None, chunk: int = 256,
     return y, h_final
 
 
-def ssm_apply(params, x, cfg: ModelConfig, state=None):
+def ssm_apply(params, x, cfg: ModelConfig, state=None, lens=None):
     """Full Mamba-2 mixer.  x: (B, S, D).
 
     state: None (prefill/train from zero) or dict(conv=(B,K-1,Di),
-    ssm=(B,H,N,P)) for chunk-wise/streaming use.  Returns (y, new_state).
+    ssm=(B,H,N,P)) for chunk-wise/streaming use.  lens: (B,) rows' own
+    lengths in a right-padded batch, or None; a padded position has dt = 0
+    and x = 0, so it leaves the state as it was.  Returns (y, new_state).
     """
     b, s, d = x.shape
     nh, p = cfg.ssm_n_heads, cfg.ssm_headdim
     z, xin, bmat, cmat, dt = _split_proj(params, x, cfg)
     conv_state = None if state is None else state["conv"]
-    xin, new_conv = _causal_conv(xin, params["conv"], conv_state)
+    xin, new_conv = _causal_conv(xin, params["conv"], conv_state, lens)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt_bias"][None, None, :])
+    if lens is not None:
+        live = (jnp.arange(s) < lens[:, None])[..., None]
+        dt = jnp.where(live, dt, 0.0)
+        xin = jnp.where(live, xin, 0)
     a = -jnp.exp(params["a_log"])
     xh = xin.reshape(b, s, nh, p)
     h0 = None if state is None else state["ssm"]

@@ -70,8 +70,15 @@ def init_layer_cache(kind: str, cfg: ModelConfig, batch: int, max_len: int,
 
 
 def _attention_seq(p_attn, x, cfg: ModelConfig, positions, window,
-                   cache=None, cache_write_pos: int = 0):
-    """Full-sequence attention (train/prefill).  Returns (out, new_cache)."""
+                   cache=None, lens=None):
+    """Full-sequence attention (train/prefill).  Returns (out, new_cache).
+
+    ``lens`` (B,): each row's own length, needed with a ``cache`` (rows
+    right-padded to ``S``).  A pad lies after a row's last token, so the
+    causal mask keeps it out of the row's outputs; its K/V are written
+    past the row's length, where decode masks them until it overwrites
+    them.
+    """
     q = jnp.einsum("bsd,dhk->bshk", x, p_attn["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p_attn["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p_attn["wv"])
@@ -94,34 +101,39 @@ def _attention_seq(p_attn, x, cfg: ModelConfig, positions, window,
     if cache is not None:
         size = cache["k"].shape[1]
         if s <= size:
-            kw, vw = k, v
-            pos = cache_write_pos
-        else:  # keep the trailing window
-            kw = jax.lax.dynamic_slice_in_dim(k, s - size, size, axis=1)
-            vw = jax.lax.dynamic_slice_in_dim(v, s - size, size, axis=1)
-            pos = 0
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], kw, pos, 1),
-            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], vw, pos, 1),
-        }
+            new_cache = {
+                "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, 1),
+                "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, 1),
+            }
+        else:  # ring buffer: slot j keeps the row's latest position = j mod size
+            last = (lens - 1)[:, None]
+            slot_pos = last - jnp.mod(last - jnp.arange(size), size)
+            idx = jnp.maximum(slot_pos, 0)[:, :, None, None]
+            new_cache = {"k": jnp.take_along_axis(k, idx, axis=1),
+                         "v": jnp.take_along_axis(v, idx, axis=1)}
     return jnp.einsum("bshk,hkd->bsd", out, p_attn["wo"]), new_cache
 
 
 def _attention_step(p_attn, x, cfg: ModelConfig, cache, cache_len, window):
-    """One-token decode.  x: (B, 1, D); cache_len: scalar tokens so far."""
+    """One-token decode.  x: (B, 1, D); cache_len: (B,) tokens so far.
+
+    Each row's token takes its own position and writes its K/V in its own
+    slot, in place (a ring buffer where the cache is shorter than the
+    sequence).
+    """
     q = jnp.einsum("bsd,dhk->bshk", x, p_attn["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p_attn["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p_attn["wv"])
-    pos = jnp.full((x.shape[0], 1), cache_len, jnp.int32)
+    pos = cache_len[:, None]
     q = apply_rope(q, pos, cfg.rope_theta)
     k = apply_rope(k, pos, cfg.rope_theta)
     size = cache["k"].shape[1]
-    slot = jnp.where(jnp.asarray(size) > 0,
-                     jnp.mod(cache_len, size), 0)  # ring-buffer write
-    kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, 1)
-    vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, 1)
+    rows, slot = jnp.arange(x.shape[0]), jnp.mod(cache_len, size)
+    kc = cache["k"].at[rows, slot].set(k[:, 0], unique_indices=True,
+                                       mode="promise_in_bounds")
+    vc = cache["v"].at[rows, slot].set(v[:, 0], unique_indices=True,
+                                       mode="promise_in_bounds")
     n_valid = jnp.minimum(cache_len + 1, size)
-    n_valid = jnp.broadcast_to(n_valid, (x.shape[0],))
     if cfg.kernel_impl != "jnp":
         from repro.kernels import ops
         out = ops.decode_attention(q[:, 0], kc, vc, n_valid, window=window,
@@ -136,8 +148,12 @@ def _attention_step(p_attn, x, cfg: ModelConfig, cache, cache_len, window):
 
 
 def block_apply_seq(p, x, kind: str, cfg: ModelConfig, positions,
-                    cache=None, window_override=None):
-    """Full-sequence residual block.  Returns (x, new_cache, aux_loss)."""
+                    cache=None, window_override=None, lens=None):
+    """Full-sequence residual block.  Returns (x, new_cache, aux_loss).
+
+    ``lens`` (B,): each row's own length where the batch is right-padded
+    (None: all ``S``); a row's cache or state is its own at that length.
+    """
     _, norm = make_norm(cfg.norm)
     aux = jnp.zeros((), jnp.float32)
     h = norm(p["ln1"], x)
@@ -146,7 +162,7 @@ def block_apply_seq(p, x, kind: str, cfg: ModelConfig, positions,
             cfg.local_window if kind == "attn" and cfg.arch_type == "hybrid"
             else cfg.sliding_window)
         a_out, new_cache = _attention_seq(
-            p["attn"], h, cfg, positions, window, cache)
+            p["attn"], h, cfg, positions, window, cache, lens)
         x = x + a_out
         h2 = norm(p["ln2"], x)
         if kind == "moe":
@@ -155,10 +171,11 @@ def block_apply_seq(p, x, kind: str, cfg: ModelConfig, positions,
             m_out = mlp(p["mlp"], h2, cfg.activation)
         x = x + m_out
     elif kind == "ssm":
-        s_out, new_cache = ssm_mod.ssm_apply(p["ssm"], h, cfg, cache)
+        s_out, new_cache = ssm_mod.ssm_apply(p["ssm"], h, cfg, cache, lens)
         x = x + s_out
     elif kind == "rglru":
-        r_out, new_cache = rglru_mod.rglru_apply(p["rglru"], h, cfg, cache)
+        r_out, new_cache = rglru_mod.rglru_apply(p["rglru"], h, cfg, cache,
+                                               lens)
         x = x + r_out
         h2 = norm(p["ln2"], x)
         x = x + mlp(p["mlp"], h2, cfg.activation)
@@ -208,11 +225,13 @@ def is_homogeneous(cfg: ModelConfig) -> bool:
 
 
 def stack_apply_seq(layers_params, x, cfg: ModelConfig, positions,
-                    caches=None, remat: bool = False, window_override=None):
+                    caches=None, remat: bool = False, window_override=None,
+                    lens=None):
     """Run all layers over a full sequence.
 
     layers_params: stacked pytree (homogeneous) or list (hybrid).
     caches: stacked cache pytree / list / None.
+    lens: (B,) rows' own lengths in a right-padded batch, or None.
     Returns (x, new_caches, total_aux).
     """
     kinds = cfg.layer_types()
@@ -227,7 +246,7 @@ def stack_apply_seq(layers_params, x, cfg: ModelConfig, positions,
             else:
                 lp, cache = xs
             xo, ncache, a = block_apply_seq(
-                lp, xc, kind, cfg, positions, cache, window_override)
+                lp, xc, kind, cfg, positions, cache, window_override, lens)
             return (xo, aux + a), ncache
 
         if remat:
@@ -245,7 +264,7 @@ def stack_apply_seq(layers_params, x, cfg: ModelConfig, positions,
 
         def fn(lp, xc, cch, kind=kind):
             return block_apply_seq(lp, xc, kind, cfg, positions, cch,
-                                   window_override)
+                                   window_override, lens)
 
         if remat:
             fn = jax.checkpoint(fn)

@@ -87,6 +87,8 @@ class ServedBatch(NamedTuple):
     one schedule, the whole chip; ``n`` its size; launch when formed, done
     when ``generate`` returned); ``dispatch_ms``, ``first_token_ms`` and
     ``last_token_ms`` are ``ModelRunner.generate``'s host stamps.
+    ``prompt_len`` is the batch's padded prompt length, ``row_lens`` the
+    rows' own.
     """
 
     span: BatchSpan
@@ -97,6 +99,7 @@ class ServedBatch(NamedTuple):
     dispatch_ms: float
     first_token_ms: float
     last_token_ms: float
+    row_lens: tuple[int, ...] = ()
 
 
 class SleepSpan(NamedTuple):

@@ -81,7 +81,7 @@ def test_decode_step_shapes(arch_id):
     toks = jax.random.randint(key, (2, 1), 0, cfg.vocab_size)
     logits, cache2 = jax.jit(model.decode_step)(params, cache, toks)
     assert logits.shape == (2, 1, cfg.padded_vocab)
-    assert int(cache2["len"]) == 1
+    np.testing.assert_array_equal(np.asarray(cache2["len"]), [1, 1])
     assert np.all(np.isfinite(np.asarray(logits, np.float32)))
 
 
@@ -136,3 +136,45 @@ def test_sliding_window_decode_matches_windowed_forward():
     np.testing.assert_allclose(np.asarray(lg[:, 0]),
                                np.asarray(logits[:, -1]),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("arch_id", ["yi-9b", "mamba2-780m",
+                                     "recurrentgemma-2b", "internvl2-76b"])
+def test_right_padded_rows_decode_as_they_do_alone(arch_id):
+    """Each row of a batch right-padded with PAD gets, at fp32, the prefill
+    and decode logits it gets alone, and its own cache length.  The long
+    row outgrows recurrentgemma's local-attention ring buffer (64)."""
+    from repro.models.model import PAD
+
+    cfg = get_smoke_config(arch_id)
+    model = Model(cfg, dtype=jnp.float32)
+    params = model.init(jax.random.key(6))
+    rng = np.random.default_rng(6)
+    lens, n_steps, max_len = (5, 70), 3, 96
+    prompts = [rng.integers(0, cfg.vocab_size, s, dtype=np.int32) for s in lens]
+    steps = rng.integers(0, cfg.vocab_size, (len(lens), n_steps), dtype=np.int32)
+    padded = np.full((len(lens), max(lens)), PAD, np.int32)
+    for i, p in enumerate(prompts):
+        padded[i, :len(p)] = p
+    extra = {}
+    if cfg.arch_type == "vlm":
+        extra = {"patch_embeds": jax.random.normal(
+            key=jax.random.key(7), shape=(1, 8, cfg.d_model))}
+    prefill, decode = jax.jit(model.prefill), jax.jit(model.decode_step)
+
+    def serve(tokens, next_tokens):
+        batch = {"tokens": jnp.asarray(tokens)}
+        batch.update({k: jnp.repeat(v, len(tokens), 0) for k, v in extra.items()})
+        logits, cache = prefill(params, batch, model.init_cache(len(tokens), max_len))
+        out = [logits[:, 0]]
+        for j in range(n_steps):
+            logits, cache = decode(params, cache, jnp.asarray(next_tokens[:, j:j + 1]))
+            out.append(logits[:, 0])
+        return np.stack(out, axis=1), np.asarray(cache["len"])
+
+    together, got_lens = serve(padded, steps)
+    n_prefix = 8 if extra else 0
+    np.testing.assert_array_equal(got_lens, np.array(lens) + n_prefix + n_steps)
+    for i, p in enumerate(prompts):
+        alone, _ = serve(p[None], steps[i:i + 1])
+        np.testing.assert_allclose(together[i], alone[0], rtol=1e-4, atol=1e-4)

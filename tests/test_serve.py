@@ -6,6 +6,7 @@ decode agrees with a teacher-forced ``forward``.
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -15,6 +16,9 @@ from repro.core.hardware import AcceleratorSpec, ClusterSpec
 from repro.core.profiles import ModelProfile
 from repro.kernels import ops
 from repro.launch import serve
+from repro.launch.mesh import make_serving_mesh
+from repro.models import Model
+from repro.obs.served import RECORDER
 
 MIX = ("chatglm3-6b", "mamba2-780m")
 
@@ -53,8 +57,39 @@ def test_greedy_decode_equals_teacher_forced_forward(served, arch):
     assert check["max_abs_diff"] <= check["tol"] / 8, check
 
 
-def test_served_tokens_do_not_depend_on_batch_mates(served):
-    """A request decodes the same tokens alone and in a batch of two."""
+def float32_runner(arch: str) -> serve.ModelRunner:
+    """A smoke runner whose weights, cache and programs are float32, with
+    the batches of one and two rows compiled."""
+    cfg = get_smoke_config(arch)
+    model = Model(cfg, dtype=jnp.float32)
+    runner = serve.ModelRunner(cfg, make_serving_mesh(jax.devices()[:1]),
+                               serve.SMOKE_SHAPES, 0,
+                               params=model.init(jax.random.key(5)))
+    runner.model = model
+    runner.compile(batches=(1, 2))
+    return runner
+
+
+@pytest.mark.parametrize("arch", MIX)
+def test_served_tokens_do_not_depend_on_batch_mates(arch):
+    """Two requests of different prompt lengths and generation lengths
+    served as one batch get exactly the tokens each gets alone."""
+    runner = float32_runner(arch)
+    rng = np.random.default_rng(2)
+    reqs = [serve.ServeRequest(runner.name, 0.0, 1e4, rng.integers(
+        0, runner.cfg.vocab_size, s, dtype=np.int32), g)
+        for s, g in zip(serve.SMOKE_SHAPES.prompt_lens, (7, 4))]
+    serve.replay([runner], reqs, {runner.name: 2})
+    (batch,) = RECORDER.window.batches
+    assert batch.row_lens == serve.SMOKE_SHAPES.prompt_lens
+    for r in reqs:
+        alone = runner.generate(r.prompt[None], r.max_new)
+        np.testing.assert_array_equal(r.output, alone.tokens[0])
+
+
+def test_a_bf16_batch_of_two_agrees_with_each_row_alone(served):
+    """In the served precision, a request decodes the same tokens alone
+    and in a batch of two, within the logit bound."""
     runner = served.runners[0]
     s = serve.SMOKE_SHAPES.prompt_lens[0]
     prompts = np.random.default_rng(2).integers(

@@ -16,6 +16,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.launch import serve
+from repro.obs import served as served_mod
 from repro.obs.served import RECORDER
 from repro.obs.spans import BatchSpan, ServedBatch
 
@@ -165,3 +166,49 @@ def test_print_run_reports_the_window(served, capsys):
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert last.startswith("window first_token_p90_ms=")
     assert "wake_late_max_ms=" in last and last.endswith("compiles=0")
+
+
+def test_a_queue_of_two_prompt_lengths_is_served_as_one_batch(served):
+    """Both prompt lengths are queued when the batch forms: one batch takes
+    both, at the longer length, and each request keeps its own tokens."""
+    runner = served.runners[0]
+    short, long_ = serve.SMOKE_SHAPES.prompt_lens
+    rng = np.random.default_rng(4)
+    reqs = [serve.ServeRequest(runner.name, 0.0, 1e4,
+                               rng.integers(0, 64, s, np.int32), g)
+            for s, g in ((long_, 4), (short, 8))]
+    serve.replay([runner], reqs, {runner.name: 2})
+    w = RECORDER.window
+    assert_consistent(w)
+    (b,) = w.batches
+    assert b.row_lens == (long_, short) and b.prompt_len == long_
+    assert b.request_ids == (0, 1) and b.steps == 8
+    assert [len(r.output) for r in reqs] == [4, 8]
+
+
+def test_the_mixed_batch_share_reads_a_hand_built_window(monkeypatch):
+    """``batch_mixed_len_pct``: the share of batches with more than one
+    prompt length among their rows."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from bench.spec import Benchmark
+
+    rec = served_mod.Recorder()
+    w = served_mod.Window(0.0, 0, ["m"] * 5, [0.0] * 5, [0.0] * 5,
+                          [0, 0, 1, 2, 2], [5.0] * 5)
+
+    def batch(bid, ids, lens):
+        return ServedBatch(BatchSpan("batch", 0, 0, 0.0, 9.0, "m", len(ids)),
+                           bid, max(lens), 4, ids, 1.0, 2.0, 8.0, lens)
+
+    w.batches = [batch(0, (0, 1), (128, 512)), batch(1, (2,), (256,)),
+                 batch(2, (3, 4), (384, 384))]
+    rec.window = w
+    monkeypatch.setattr(served_mod, "RECORDER", rec)
+    bench = Benchmark(root)
+    assert bench.read("batch_mixed_len_pct", {}) == pytest.approx(100.0 / 3)
+    # a program that logs no row lengths reads nothing
+    w.batches = [b._replace(row_lens=()) for b in w.batches]
+    assert bench.read("batch_mixed_len_pct", {}) is None
+    rec.window = None
+    assert bench.read("batch_mixed_len_pct", {}) is None
